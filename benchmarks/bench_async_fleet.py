@@ -68,7 +68,7 @@ def _make_sim_step(probs, m, profile, buffer_size, use_kernel, n=None, mesh=None
                 fleet_state_sharding(mesh, n, state, axis),
             )
 
-    def step(state, key):
+    def step(state, key, data=None):  # no client data: nothing trains
         ev, ages, clock = state["ev"], state["sched"], state["clock"]
         k_sel, k_lat = jax.random.split(key)
         k_gap = jax.random.fold_in(k_sel, 103)

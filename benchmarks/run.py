@@ -116,6 +116,9 @@ def main() -> None:
                          "default is deliberately loose — shared CI boxes "
                          "jitter ~2x; tighten locally for real perf work")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     want = set(args.only.split(",")) if args.only else None
 
     def on(name):

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core.aoi import age_update
 
@@ -147,7 +147,7 @@ def oldest_age_step_sharded(mesh: Mesh, axis: str, k: int):
         mesh=mesh,
         in_specs=(spec,),
         out_specs=(spec, spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(f)
 
@@ -190,10 +190,10 @@ def sharded_next_k_events(
 
     # outputs are replicated by construction (every device merges the same
     # gathered candidates); the static replication checker can't see that
-    # through the gather + indexing, hence check_rep=False
+    # through the gather + indexing, hence check_vma=False
     merge = shard_map(
         local, mesh=mesh, in_specs=(spec,), out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
 
     def next_k(times):

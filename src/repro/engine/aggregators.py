@@ -105,7 +105,7 @@ def cohort_sharded_apply(
     the *unstacked* global tree, replicated, broadcast lazily inside
     ``accumulate``.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     if not agg.additive:

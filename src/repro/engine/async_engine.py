@@ -110,7 +110,8 @@ class AsyncEngine:
             self.defense = None
         self._init_state, core = self._build_step()
         self._chunk = ChunkRunner(
-            core, aux_keys=("loss", "clock", "version", "buffer_fill")
+            core, aux_keys=("loss", "clock", "version", "buffer_fill"),
+            data=self.task.client_data,
         )
 
     def _build_step(self):
@@ -253,9 +254,10 @@ def _make_async_step(
     aggregate=None, cohort_pad: int = 0, topo=None, faults=None,
     defense=None,
 ):
-    """Builds ``(init_state, step core)`` with ``step(state, key) ->
+    """Builds ``(init_state, step core)`` with ``step(state, key, data) ->
     (state, aux)`` — the pure function the chunked scan body folds over
-    (``ChunkRunner`` also drives single steps through a length-1 chunk).
+    (``ChunkRunner`` also drives single steps through a length-1 chunk);
+    ``data`` is ``task.client_data``, passed in rather than closed over.
 
     The optional hooks are the mesh-sharding seam (``repro.engine.sharded``
     supplies them; the single-device engine runs with identity defaults):
@@ -400,7 +402,7 @@ def _make_async_step(
             }
         return state
 
-    def step(state, key):
+    def step(state, key, data):
         ev, sched, stats = state["ev"], state["sched"], state["stats"]
         clock, version = state["clock"], state["version"]
         # same key split as the sync round so the degenerate case is
@@ -542,7 +544,7 @@ def _make_async_step(
         disp_params = cohort_layout(
             jax.tree.map(lambda h: h[read_ver % H], state["hist"])
         )
-        shards = cohort_layout(jax.tree.map(lambda a: a[idx], task.client_data))
+        shards = cohort_layout(jax.tree.map(lambda a: a[idx], data))
         keys = jax.random.split(k_local, B)
         if cohort_pad:
             # the first B keys must stay the exact draws of the unpadded

@@ -36,43 +36,59 @@ _RUNNER_KEYS = ("k_run", "load_acc")
 
 
 class ChunkRunner:
-    """Compile-once-per-shape chunked driver over ``step(state, key)``.
+    """Compile-once-per-shape chunked driver over ``step(state, key, data)``.
 
     ``step_fn`` is the engine's pure per-step function: it takes the
     engine's jittable state (without the runner-owned ``k_run`` /
-    ``load_acc`` entries) and a folded key, and returns ``(state, aux)``
-    where ``aux`` contains at least ``send`` (the (n,) bool selection
-    vector) plus any per-step scalars. ``aux_keys`` names the aux entries
-    stacked and returned per step; ``send`` is additionally stacked when
-    the caller asks for history.
+    ``load_acc`` entries), a folded key and ``data``, and returns
+    ``(state, aux)`` where ``aux`` contains at least ``send`` (the (n,)
+    bool selection vector) plus any per-step scalars. ``aux_keys`` names
+    the aux entries stacked and returned per step; ``send`` is
+    additionally stacked when the caller asks for history.
+
+    ``data`` is the read-only input every step reads (the task's client
+    data). It enters each compiled chunk as an argument, never donated:
+    closed over, XLA would embed it as a constant, and at fleet scale
+    (gigabytes of client examples) that costs minutes of compile and a
+    program too large for the persistent compilation cache.
     """
 
-    def __init__(self, step_fn: Callable, aux_keys: Tuple[str, ...]):
+    def __init__(self, step_fn: Callable, aux_keys: Tuple[str, ...],
+                 data=None):
         self._step_fn = step_fn
         self._aux_keys = aux_keys
+        self._data = data
         self._compiled: Dict[Tuple[int, bool], Callable] = {}
 
     def _build(self, length: int, with_history: bool) -> Callable:
         step_fn, aux_keys = self._step_fn, self._aux_keys
 
-        def body(carry, r):
-            key = jax.random.fold_in(carry["k_run"], r)
-            inner = {k: v for k, v in carry.items() if k not in _RUNNER_KEYS}
-            inner, aux = step_fn(inner, key)
-            carry = {
-                **inner,
-                "k_run": carry["k_run"],
-                "load_acc": update_selection_accum(carry["load_acc"], aux["send"]),
-            }
-            ys = {k: aux[k] for k in aux_keys}
-            if with_history:
-                ys["send"] = aux["send"]
-            return carry, ys
+        def chunk(state, data, r0):
+            def body(carry, r):
+                key = jax.random.fold_in(carry["k_run"], r)
+                inner = {k: v for k, v in carry.items() if k not in _RUNNER_KEYS}
+                inner, aux = step_fn(inner, key, data)
+                carry = {
+                    **inner,
+                    "k_run": carry["k_run"],
+                    "load_acc": update_selection_accum(
+                        carry["load_acc"], aux["send"]),
+                }
+                ys = {k: aux[k] for k in aux_keys}
+                if with_history:
+                    ys["send"] = aux["send"]
+                return carry, ys
 
-        def chunk(state, r0):
             return jax.lax.scan(body, state, r0 + jnp.arange(length))
 
         return jax.jit(chunk, donate_argnums=0)
+
+    def _fn(self, length: int, with_history: bool) -> Callable:
+        key = (length, with_history)
+        fn = self._compiled.get(key)
+        if fn is None:
+            fn = self._compiled[key] = self._build(length, with_history)
+        return fn
 
     def __call__(self, state: Dict, r0: int, length: int, with_history: bool):
         """Advance ``length`` steps from global step ``r0``.
@@ -81,11 +97,14 @@ class ChunkRunner:
         ``stacked_aux`` leaf carrying a leading ``length`` axis, still on
         device (the caller decides when to transfer).
         """
-        key = (length, with_history)
-        fn = self._compiled.get(key)
-        if fn is None:
-            fn = self._compiled[key] = self._build(length, with_history)
-        return fn(state, jnp.asarray(r0, jnp.int32))
+        return self._fn(length, with_history)(
+            state, self._data, jnp.asarray(r0, jnp.int32))
+
+    def lower(self, state: Dict, r0: int, length: int, with_history: bool):
+        """The chunk that ``__call__`` would run, lowered and not run
+        (``jax.stages.Lowered``): its HLO shows which kernels it calls."""
+        return self._fn(length, with_history).lower(
+            state, self._data, jnp.asarray(r0, jnp.int32))
 
 
 def step_once(runner: ChunkRunner, state: Dict, r: int):

@@ -19,6 +19,7 @@ tree as ``bases``.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional
 
 import jax
@@ -169,9 +170,9 @@ class SyncEngine:
         have_def = self.defense is not None
         stat_names = self.aggregator.stat_names
 
-        def scan_step(state, key):
+        def scan_step(state, key, data):
             params, sched, selected, loss, fstate, dstate, tel = core(
-                state["params"], state["sched"], key,
+                state["params"], state["sched"], key, data,
                 state["faults"] if have_faults else None,
                 state["defense"] if have_def else None,
             )
@@ -190,7 +191,8 @@ class SyncEngine:
                 }
             return out, {"send": selected, "loss": loss}
 
-        self._chunk = ChunkRunner(scan_step, aux_keys=("loss",))
+        self._chunk = ChunkRunner(scan_step, aux_keys=("loss",),
+                                  data=task.client_data)
 
     def init(self) -> Dict:
         cfg = self.cfg
@@ -299,7 +301,9 @@ def _make_round_core(task: FLTask, cfg: RunConfig, policy: Policy, agg: Aggregat
                      cohort_layout=None, aggregate=None, cohort_shards: int = 1,
                      faults=None, defense=None):
     """The pure per-round function (no jit): shared by the legacy per-step
-    path and the scan body of the chunked hot loop.
+    path and the scan body of the chunked hot loop. It reads the clients'
+    examples from its ``data`` argument (``task.client_data``), never
+    from a closure, so no compiled round embeds them as constants.
 
     The optional hooks are the cohort-parallel seam (mirroring
     ``_make_async_step``): ``cohort_layout`` lays the cohort-stacked
@@ -359,7 +363,7 @@ def _make_round_core(task: FLTask, cfg: RunConfig, policy: Policy, agg: Aggregat
     )
     lr_fn = exponential_decay(cfg.lr0, cfg.lr_decay)
 
-    def round_fn(params, sched_state, key, fstate=None, dstate=None):
+    def round_fn(params, sched_state, key, data, fstate=None, dstate=None):
         k_sel, k_local = jax.random.split(key)
         selected, sched_state = policy.step(sched_state, k_sel)
         if have_def:
@@ -380,7 +384,7 @@ def _make_round_core(task: FLTask, cfg: RunConfig, policy: Policy, agg: Aggregat
                 fstate, jax.random.fold_in(k_fault, 1), idx, mask > 0
             )
             eff = cohort_layout(eff)
-        shards = cohort_layout(jax.tree.map(lambda a: a[idx], task.client_data))
+        shards = cohort_layout(jax.tree.map(lambda a: a[idx], data))
         lr = lr_fn(sched_state["round"] - 1)
         # the cohort axis of the global params is a lazy vmap broadcast —
         # no (width, ...) copies are materialized; aggregators see the
@@ -442,10 +446,10 @@ def _make_round_fn(task: FLTask, cfg: RunConfig, policy: Policy, agg: Aggregator
     the fault/telemetry-free 4-tuple view of the round core."""
     core = _make_round_core(task, cfg, policy, agg)
 
-    def round_fn(params, sched_state, key):
+    def round_fn(params, sched_state, key, data):
         params, sched_state, selected, loss, _, _, _ = core(
-            params, sched_state, key
+            params, sched_state, key, data
         )
         return params, sched_state, selected, loss
 
-    return jax.jit(round_fn)
+    return functools.partial(jax.jit(round_fn), data=task.client_data)

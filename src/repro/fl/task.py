@@ -7,6 +7,7 @@ production mesh) can be the federated workload.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Dict, Optional
 
 import jax
@@ -68,7 +69,7 @@ def make_cnn_task(
         return -jnp.take_along_axis(logp, batch["y"][:, None], axis=-1).mean()
 
     @jax.jit
-    def eval_fn(params):
+    def eval_scan(params, tx, ty):
         # batched eval to bound memory
         bs = min(500, int(tx.shape[0]))
         nb = max(tx.shape[0] // bs, 1)
@@ -107,7 +108,8 @@ def make_cnn_task(
         name=cfg.name,
         init=lambda key: cnn_mod.init_params(key, cfg),
         loss_fn=loss_fn,
-        eval_fn=eval_fn,
+        # the test set is an argument, not a constant of the program
+        eval_fn=functools.partial(eval_scan, tx=tx, ty=ty),
         client_data={"x": cx, "y": cy},
         examples_per_client=int(cx.shape[1]),
         eval_data={"x": tx[:n_used], "y": ty[:n_used]},

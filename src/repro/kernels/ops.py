@@ -63,9 +63,10 @@ def event_next_k(times, k, *, block_n=None):
     """Fleet-scale next-k-completion extraction: tiled kernel phase + tiny
     global top-k over per-tile candidates. Returns (times (k,), indices
     (k,)) of the k earliest events; slots with no pending event carry
-    ``+inf`` times (mask by finiteness)."""
+    ``+inf`` times (mask by finiteness). Values and indices equal
+    ``lax.top_k(-times, k)`` on every slot, tie order included."""
     kw = {"block_n": block_n} if block_n else {}
     vals, idx = _etopk.tile_next_k(times, k=k, interpret=_interpret(), **kw)
-    flat_v, flat_i = vals.reshape(-1), idx.reshape(-1)
+    flat_v, flat_i = vals[:, :k].reshape(-1), idx[:, :k].reshape(-1)
     neg_v, pos = jax.lax.top_k(-flat_v, k)
     return -neg_v, flat_i[pos]

@@ -29,8 +29,9 @@ from __future__ import annotations
 
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import load_metric
-from repro.engine import make_engine, run_engine
+from repro.engine import RunConfig, make_engine, run_engine
 from repro.launch._fl_cli import (
     add_common_args,
     build_run_config,
@@ -49,7 +50,7 @@ DEFAULTS = {
 }
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     add_common_args(ap, DEFAULTS)
     ap.add_argument("--buffer-size", type=int, default=None,
@@ -59,10 +60,11 @@ def main() -> None:
     ap.add_argument("--staleness-weight", type=float, default=0.5,
                     help="polynomial discount exponent a in (1+s)^-a; 0 = constant")
     ap.add_argument("--max-versions", type=int, default=8)
-    args = ap.parse_args()
+    return ap
 
-    task = build_task(args)
-    cfg = build_run_config(
+
+def build_config(args: argparse.Namespace) -> RunConfig:
+    return build_run_config(
         args, mode="async", eval_div=20,
         aggregator_kwargs={
             "staleness_mode": "const" if args.staleness_weight == 0 else "poly",
@@ -73,6 +75,13 @@ def main() -> None:
         max_versions=args.max_versions,
         profile=args.latency_profile,
     )
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    enable_compile_cache()
+    task = build_task(args)
+    cfg = build_config(args)
     engine = make_engine(task, cfg)
     shards = getattr(engine, "mesh_shards", None)
     print(

@@ -20,8 +20,9 @@ from __future__ import annotations
 
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import load_metric
-from repro.engine import SyncEngine, run_engine
+from repro.engine import RunConfig, SyncEngine, run_engine
 from repro.fl.rounds import rounds_to_target
 from repro.launch._fl_cli import (
     add_common_args,
@@ -35,14 +36,22 @@ from repro.launch._fl_cli import (
 DEFAULTS = {"rounds": 60, "clients": 100, "local_epochs": 5, "lr": 0.1}
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     add_common_args(ap, DEFAULTS)
     ap.add_argument("--target-acc", type=float, default=None)
-    args = ap.parse_args()
+    return ap
 
+
+def build_config(args: argparse.Namespace) -> RunConfig:
+    return build_run_config(args, mode="sync", eval_div=30)
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    enable_compile_cache()
     task = build_task(args)
-    cfg = build_run_config(args, mode="sync", eval_div=30)
+    cfg = build_config(args)
     engine = SyncEngine(task, cfg)
     print(f"policy={cfg.policy} n={cfg.n_clients} k={cfg.k} m={cfg.m} "
           f"rounds={cfg.rounds} aggregator={cfg.resolved_aggregator()} "

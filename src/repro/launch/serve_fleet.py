@@ -19,6 +19,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch
 from repro.engine import AsyncEngine, RunConfig, dump_json
 from repro.fl.task import make_lm_task
@@ -64,6 +65,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg_arch = get_arch(args.arch).reduced()
     task = make_lm_task(cfg_arch, args.clients, seq_len=32, docs_per_client=4,

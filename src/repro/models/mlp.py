@@ -16,7 +16,7 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import MLPSpec
@@ -80,5 +80,5 @@ def _mlp_fwd_explicit_tp(p: Dict, x: jnp.ndarray, spec: MLPSpec, mesh) -> jnp.nd
         mesh=mesh,
         in_specs=(xspec, P(None, "model"), P(None, "model"), P("model", None)),
         out_specs=xspec,
-        check_rep=False,
+        check_vma=False,
     )(x, p["w_in"], p["w_gate"], p["w_out"])

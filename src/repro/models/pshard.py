@@ -14,7 +14,7 @@ import contextlib
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 _CTX = {"mesh": None}
 
@@ -81,4 +81,4 @@ def constrain(x, *axes):
             continue
         spec.append(names if len(names) > 1 else names[0])
         used.update(names)
-    return jax.lax.with_sharding_constraint(x, P(*spec))
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*spec)))

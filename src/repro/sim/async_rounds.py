@@ -49,7 +49,10 @@ def make_async_step(
     task: FLTask, fl: FLConfig, acfg: AsyncConfig, policy: Policy
 ):
     """Builds (init_state, jitted step) for one async server step (legacy
-    helper)."""
+    helper); ``step(state, key)`` reads ``task.client_data`` as an
+    argument of the compiled step."""
+    import functools
+
     import jax
 
     from repro.engine.async_engine import _make_async_step
@@ -64,7 +67,7 @@ def make_async_step(
     init_state, step = _make_async_step(
         task, cfg, policy, agg, acfg.resolved_profile()
     )
-    return init_state, jax.jit(step)
+    return init_state, functools.partial(jax.jit(step), data=task.client_data)
 
 
 def run_async_training(

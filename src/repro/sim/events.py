@@ -76,11 +76,10 @@ def pop_events(
     """Extract the next k completions and return those clients to idle.
 
     Returns (event times (k,), client idx (k,), valid mask (k,), state').
-    Invalid slots (fewer than k events pending) may carry duplicate or
-    arbitrary indices — the kernel path emits a tile's argmax-of-nothing
-    when exhausted — so they gather client 0 data under a zero mask and
-    are scattered to an out-of-range sentinel (dropped), never to a real
-    client.
+    Invalid slots (fewer than k events pending) carry the indices of
+    idle clients or of padding, so they gather client 0 data under a zero
+    mask and are scattered to an out-of-range sentinel (dropped), never
+    to a real client.
     """
     t, idx = next_k_events(ev["t_done"], k, use_kernel=use_kernel)
     return apply_pop(ev, t, idx)
@@ -101,5 +100,5 @@ def apply_pop(
 def scatter_idx(idx: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
     """Indices for a masked scatter over popped events: masked-out slots
     go out of range so ``.at[...].set(..., mode="drop")`` ignores them —
-    duplicate indices from exhausted kernel tiles must never write back."""
+    an invalid slot names an idle client or padding, never to be written."""
     return jnp.where(mask, idx, jnp.iinfo(jnp.int32).max)

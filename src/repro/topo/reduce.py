@@ -117,7 +117,7 @@ def tiered_apply(
         return _segment_sum_tree(accs, seg, e0)
 
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         spec = P(axis)

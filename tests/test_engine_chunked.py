@@ -145,3 +145,21 @@ def test_empty_cohort_reports_nan_loss(small_task, mode):
     res = run_engine(make(small_task, cfg, policy=_never_send_policy(20)))
     assert all(np.isnan(rec.train_loss) for rec in res.records)
     assert not res.selection.any()
+
+
+@pytest.mark.parametrize("mode", ["sync", "async"])
+def test_client_data_is_a_chunk_argument(small_task, mode):
+    """Client data reaches the compiled chunk as an argument. Closed
+    over, it would be an XLA constant of every chunk program: gigabytes
+    at fleet scale, minutes of compile, too large for the compile cache."""
+    kw = dict(profile="lognormal", buffer_size=3) if mode == "async" else {}
+    make = SyncEngine if mode == "sync" else AsyncEngine
+    engine = make(small_task, _cfg(mode=mode, **kw))
+    lowered = engine._chunk.lower(engine.init(), 0, 2, False)
+    data_args = jax.tree.leaves(lowered.args_info[0][1])
+    data = jax.tree.leaves(small_task.client_data)
+    assert [a.shape for a in data_args] == [d.shape for d in data]
+    x = small_task.client_data["x"]
+    x_type = "tensor<" + "x".join(map(str, x.shape)) + "xf32>"
+    assert not [line for line in lowered.as_text().splitlines()
+                if "stablehlo.constant" in line and x_type in line]

@@ -26,8 +26,9 @@ MESH = dist.fleet_mesh(DEVICES)
 _times = st.lists(
     st.one_of(
         st.sampled_from([1.0, 2.0, 3.0, jnp.inf]),
-        st.floats(0.01, 100.0, allow_nan=False, allow_infinity=False,
-                  width=32),
+        # width-32 bounds must be float32 values themselves
+        st.floats(float(np.float32(0.01)), 100.0, allow_nan=False,
+                  allow_infinity=False, width=32),
     ),
     min_size=1, max_size=4 * DEVICES + 5,
 )

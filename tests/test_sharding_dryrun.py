@@ -70,7 +70,8 @@ from repro.models import factory, pshard
 from repro import sharding as sr
 import dataclasses
 
-mesh = jax.make_mesh((4, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 cfg = get_arch("jamba-v0.1-52b").reduced()
 cfg = dataclasses.replace(cfg, d_model=256, vocab_size=512)
 model = factory.build(cfg)

@@ -60,23 +60,29 @@ def test_mean_latency_closed_form():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("n,k,block_n,pending_frac", [
-    (64, 4, 16, 1.0),
-    (1000, 16, 128, 0.3),
-    (1000, 16, 256, 0.01),  # fewer pending events than k in most tiles
-    (513, 8, 128, 0.5),  # ragged final tile
+@pytest.mark.parametrize("n,k,block_n,pending_frac,ties", [
+    (64, 4, 16, 1.0, False),
+    (1000, 16, 128, 0.3, False),
+    (1000, 16, 256, 0.01, False),  # fewer pending events than k in most tiles
+    (513, 8, 128, 0.5, False),  # ragged final tile
+    (3000, 130, 1024, 0.5, False),  # k not a multiple of 128, n not of the block
+    (5000, 200, 1024, 0.5, True),  # heavy ties across tiles
+    (3000, 1500, 1024, 0.02, False),  # k above the block: tiles widen to k
+    (10, 3, 1024, 1.0, True),  # fleet smaller than one vreg tile
 ])
-def test_event_topk_kernel_matches_reference(n, k, block_n, pending_frac):
+def test_event_topk_kernel_matches_reference(n, k, block_n, pending_frac, ties):
     kx, km = jax.random.split(jax.random.fold_in(KEY, n * k))
-    t = jax.random.uniform(kx, (n,)) * 100
+    if ties:
+        t = jax.random.randint(kx, (n,), 0, 5).astype(jnp.float32)
+    else:
+        t = jax.random.uniform(kx, (n,)) * 100
     pending = jax.random.uniform(km, (n,)) < pending_frac
     times = jnp.where(pending, t, jnp.inf).astype(jnp.float32)
     ref_v, ref_i = ev_mod.next_k_events(times, k, use_kernel=False)
     ker_v, ker_i = ops.event_next_k(times, k, block_n=block_n)
-    np.testing.assert_allclose(np.asarray(ker_v), np.asarray(ref_v), rtol=1e-6)
-    valid = np.isfinite(np.asarray(ref_v))
-    # indices must agree wherever a real event exists
-    np.testing.assert_array_equal(np.asarray(ker_i)[valid], np.asarray(ref_i)[valid])
+    # every slot, idle (+inf) ones included, in lax.top_k's tie order
+    np.testing.assert_array_equal(np.asarray(ker_v), np.asarray(ref_v))
+    np.testing.assert_array_equal(np.asarray(ker_i), np.asarray(ref_i))
 
 
 def test_next_k_ties_break_low_index():
@@ -136,9 +142,9 @@ def test_pop_removes_events_and_is_deterministic():
 
 
 def test_pop_kernel_path_fewer_events_than_k():
-    """Exhausted kernel tiles emit duplicate real indices for their +inf
-    filler slots; the scatter back must drop them — the popped event must
-    stay cleared, not be resurrected by a stale duplicate write."""
+    """Exhausted kernel tiles emit idle clients for their +inf filler
+    slots; the scatter back must drop them — the popped event must stay
+    cleared, and no idle client may be written."""
     n = 8
     ev = ev_mod.init_event_state(n)
     ev = ev_mod.schedule_completions(
