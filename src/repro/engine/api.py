@@ -28,6 +28,7 @@ from typing import Dict, Optional, Protocol, Tuple, runtime_checkable
 
 import jax
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.engine.config import RoundRecord, RunConfig, RunResult, chunk_plan
 
@@ -91,32 +92,52 @@ def keep_history(cfg: RunConfig) -> bool:
 
 
 def run_engine(engine: Engine, progress: bool = False) -> RunResult:
-    """Drive an engine for ``cfg.rounds`` steps and package the result."""
+    """Drive an engine for ``cfg.rounds`` steps and package the result.
+
+    Each phase of the loop is a host span in the profiler's trace
+    (``jax.profiler.TraceAnnotation``, about a microsecond each when no
+    profiler runs): ``run_engine.init`` and ``run_engine.finalize`` around
+    the loop, and inside each ``run_engine.chunk`` step span
+    ``run_engine.dispatch`` (the chunk's enqueue), ``run_engine.pull``
+    (its one host transfer), ``run_engine.history``, and on eval steps
+    ``run_engine.evaluate`` and ``run_engine.record``."""
     from repro.engine.chunk import dealias_pytree
 
     cfg = engine.cfg
     steps = cfg.rounds
-    state = dealias_pytree(engine.init())
-    keep_hist = keep_history(cfg)
-    sel_hist: Optional[np.ndarray] = (
-        np.zeros((steps, cfg.n_clients), dtype=bool) if keep_hist else None
-    )
-    records = []
-    t0 = time.time()
+    with TraceAnnotation("run_engine.init"):
+        state = dealias_pytree(engine.init())
+        keep_hist = keep_history(cfg)
+        sel_hist: Optional[np.ndarray] = (
+            np.zeros((steps, cfg.n_clients), dtype=bool) if keep_hist else None
+        )
+        records = []
+    t0 = time.perf_counter()
     for r0, length, do_eval in chunk_plan(
         steps, cfg.eval_every, cfg.resolved_steps_per_chunk()
     ):
-        state, aux = engine.run_chunk(state, r0, length, keep_hist)
-        aux = jax.device_get(aux)  # the chunk's one device -> host transfer
-        if keep_hist:
-            sel_hist[r0:r0 + length] = aux.pop("send")
-        if do_eval:
-            r = r0 + length - 1
-            # engines own their eval: cohort-sharded engines score the
-            # held-out set with the eval-batch axis sharded over the mesh
-            ev = engine.evaluate(state)
-            rec = engine.record(r, {k: v[-1] for k, v in aux.items()}, ev)
-            records.append(rec)
-            if progress:
-                print(engine.progress_line(rec, time.time() - t0), flush=True)
-    return engine.finalize(state, records, sel_hist, time.time() - t0)
+        with StepTraceAnnotation("run_engine.chunk", step_num=r0):
+            with TraceAnnotation("run_engine.dispatch"):
+                state, aux = engine.run_chunk(state, r0, length, keep_hist)
+            with TraceAnnotation("run_engine.pull"):
+                # the chunk's one device -> host transfer
+                aux = jax.device_get(aux)
+            if keep_hist:
+                with TraceAnnotation("run_engine.history"):
+                    sel_hist[r0:r0 + length] = aux.pop("send")
+            if do_eval:
+                # engines own their eval: cohort-sharded engines score the
+                # held-out set with the eval-batch axis sharded over the mesh
+                with TraceAnnotation("run_engine.evaluate"):
+                    ev = engine.evaluate(state)
+                with TraceAnnotation("run_engine.record"):
+                    r = r0 + length - 1
+                    rec = engine.record(r, {k: v[-1] for k, v in aux.items()},
+                                        ev)
+                    records.append(rec)
+                    if progress:
+                        print(engine.progress_line(
+                            rec, time.perf_counter() - t0), flush=True)
+    wall_time_s = time.perf_counter() - t0
+    with TraceAnnotation("run_engine.finalize"):
+        return engine.finalize(state, records, sel_hist, wall_time_s)

@@ -411,287 +411,296 @@ def _make_async_step(
         k_lat = jax.random.fold_in(k_sel, 101)
         k_gap = jax.random.fold_in(k_sel, 103)
 
-        # --- admission control: idle+available clients consult the policy
-        prev_ages = sched["ages"]
-        idle = jnp.isinf(ev["t_done"])
-        available = ev["next_avail"] <= clock
-        want, sched = policy.step(sched, k_sel)
-        send = want & idle & available
-        if have_def:
-            # quarantined clients are vetoed at the admission seam (they
-            # still age); probation clients stay selectable so they keep
-            # generating evidence for re-admission
-            dstate = state["defense"]
-            send = send & ~defense.blocked(dstate)
-        # only actual dispatches reset the AoI clock; everyone else ages
-        sched = {**sched, "ages": age_update(prev_ages, send)}
-        ep_sx, ep_sx2, ep_cnt = peak_age_accumulate(
-            prev_ages, send, stats["ep_sx"], stats["ep_sx2"], stats["ep_cnt"]
-        )
+        with jax.named_scope("admission"):
+            # --- admission control: idle+available clients consult the policy
+            prev_ages = sched["ages"]
+            idle = jnp.isinf(ev["t_done"])
+            available = ev["next_avail"] <= clock
+            want, sched = policy.step(sched, k_sel)
+            send = want & idle & available
+            if have_def:
+                # quarantined clients are vetoed at the admission seam (they
+                # still age); probation clients stay selectable so they keep
+                # generating evidence for re-admission
+                dstate = state["defense"]
+                send = send & ~defense.blocked(dstate)
+            # only actual dispatches reset the AoI clock; everyone else ages
+            sched = {**sched, "ages": age_update(prev_ages, send)}
+            ep_sx, ep_sx2, ep_cnt = peak_age_accumulate(
+                prev_ages, send, stats["ep_sx"], stats["ep_sx2"], stats["ep_cnt"]
+            )
 
-        # --- dispatch: sample wall-clock latencies, mark in flight.
-        # zero-dropout profiles skip the dropout path entirely — the 102
-        # key fold here plus the constant-folding of the zeros mask
-        # (sample_dropout already skips the (n,) draw itself). No other
-        # key depends on the 102 fold, so results are unchanged — pinned
-        # by tests/test_cohort_engine.py
-        latency = lat_mod.sample_latency(k_lat, profile, state["speed"])
-        if tiered:
-            # fold 104: per-hop DAG latency. Only drawn when a multi-tier
-            # topology is armed, so the star key schedule is untouched
-            latency = latency + hop_fn(jax.random.fold_in(k_sel, 104))
-        if have_faults:
-            fstate = state["faults"]
-            # fold 105: the fault set's dedicated key (sub-folds:
-            # 0 dispatch, 1 pop, 2 corruption noise) — armed only when
-            # faults are, so the fault-free key schedule is untouched
-            k_fault = jax.random.fold_in(k_sel, 105)
-            if faults.has_dispatch:
-                fstate, latency = faults.on_dispatch(
-                    fstate, jax.random.fold_in(k_fault, 0), send, latency
-                )
-        if hb_timeout > 0:
-            # dispatch is a heartbeat: the client pulled the model at
-            # the current clock
-            hb = hb_mod.beat(state["hb"], send, clock)
-        if profile.dropout > 0:
-            dropped = lat_mod.sample_dropout(
-                jax.random.fold_in(k_sel, 102), profile, n
-            )
-        else:
-            dropped = jnp.zeros((n,), jnp.bool_)
-        ev = ev_mod.schedule_completions(ev, send, clock, latency, version, dropped)
-
-        # --- deadline-based re-dispatch of expired in-flight dispatches:
-        # a dispatch the server has not heard back from within the
-        # timeout is re-issued at the current version with a fresh
-        # latency (folds 106/107), at most redispatch_retries times —
-        # then written off (t_done=inf frees the client to be selected
-        # again). The original dispatch's dropout coin is preserved: a
-        # retry re-attempts delivery, not the client's fate.
-        if rd_on:
-            rd_t = jnp.where(send, clock, state["rd"]["t_disp"])
-            rd_cnt = jnp.where(send, 0, state["rd"]["retries"])
-            inflight = ~jnp.isinf(ev["t_done"])
-            exp = inflight & hb_mod.expired(
-                rd_t, clock, float(cfg.redispatch_timeout)
-            )
-            retry = exp & (rd_cnt < cfg.redispatch_retries)
-            give_up = exp & ~retry
-            rd_lat = lat_mod.sample_latency(
-                jax.random.fold_in(k_sel, 106), profile, state["speed"]
-            )
+        with jax.named_scope("dispatch"):
+            # --- dispatch: sample wall-clock latencies, mark in flight.
+            # zero-dropout profiles skip the dropout path entirely — the 102
+            # key fold here plus the constant-folding of the zeros mask
+            # (sample_dropout already skips the (n,) draw itself). No other
+            # key depends on the 102 fold, so results are unchanged — pinned
+            # by tests/test_cohort_engine.py
+            latency = lat_mod.sample_latency(k_lat, profile, state["speed"])
             if tiered:
-                rd_lat = rd_lat + hop_fn(jax.random.fold_in(k_sel, 107))
+                # fold 104: per-hop DAG latency. Only drawn when a multi-tier
+                # topology is armed, so the star key schedule is untouched
+                latency = latency + hop_fn(jax.random.fold_in(k_sel, 104))
+            if have_faults:
+                fstate = state["faults"]
+                # fold 105: the fault set's dedicated key (sub-folds:
+                # 0 dispatch, 1 pop, 2 corruption noise) — armed only when
+                # faults are, so the fault-free key schedule is untouched
+                k_fault = jax.random.fold_in(k_sel, 105)
+                if faults.has_dispatch:
+                    fstate, latency = faults.on_dispatch(
+                        fstate, jax.random.fold_in(k_fault, 0), send, latency
+                    )
+            if hb_timeout > 0:
+                # dispatch is a heartbeat: the client pulled the model at
+                # the current clock
+                hb = hb_mod.beat(state["hb"], send, clock)
+            if profile.dropout > 0:
+                dropped = lat_mod.sample_dropout(
+                    jax.random.fold_in(k_sel, 102), profile, n
+                )
+            else:
+                dropped = jnp.zeros((n,), jnp.bool_)
+            ev = ev_mod.schedule_completions(ev, send, clock, latency, version, dropped)
+
+            # --- deadline-based re-dispatch of expired in-flight dispatches:
+            # a dispatch the server has not heard back from within the
+            # timeout is sent again at the current version with a fresh
+            # latency (folds 106/107), at most redispatch_retries times —
+            # then written off (t_done=inf frees the client to be selected
+            # again). The original dispatch's dropout coin is preserved: a
+            # retry re-attempts delivery, not the client's fate.
+            if rd_on:
+                rd_t = jnp.where(send, clock, state["rd"]["t_disp"])
+                rd_cnt = jnp.where(send, 0, state["rd"]["retries"])
+                inflight = ~jnp.isinf(ev["t_done"])
+                exp = inflight & hb_mod.expired(
+                    rd_t, clock, float(cfg.redispatch_timeout)
+                )
+                retry = exp & (rd_cnt < cfg.redispatch_retries)
+                give_up = exp & ~retry
+                rd_lat = lat_mod.sample_latency(
+                    jax.random.fold_in(k_sel, 106), profile, state["speed"]
+                )
+                if tiered:
+                    rd_lat = rd_lat + hop_fn(jax.random.fold_in(k_sel, 107))
+                ev = {
+                    **ev,
+                    "t_done": jnp.where(
+                        retry, clock + rd_lat,
+                        jnp.where(give_up, jnp.inf, ev["t_done"]),
+                    ),
+                    "disp_ver": jnp.where(retry, version, ev["disp_ver"]),
+                }
+                rd = {
+                    "t_disp": jnp.where(retry, clock, rd_t),
+                    "retries": rd_cnt + retry.astype(jnp.int32),
+                }
+                rd_retried = retry.astype(jnp.float32).sum()
+                rd_expired = exp.astype(jnp.float32).sum()
+
+        with jax.named_scope("pop"):
+            # --- pop the next B completions, advance the simulated clock
+            t_ev, idx, valid, ev = pop(ev)
+            if cohort_pad:
+                # pad the cohort to the mesh multiple with invalid slots:
+                # t=+inf/valid=False masks them out of the clock advance, the
+                # weights, the telemetry, and both scatters, exactly like an
+                # under-filled buffer slot
+                t_ev = jnp.concatenate(
+                    [t_ev, jnp.full((cohort_pad,), jnp.inf, t_ev.dtype)]
+                )
+                idx = jnp.concatenate([idx, jnp.zeros((cohort_pad,), idx.dtype)])
+                valid = jnp.concatenate(
+                    [valid, jnp.zeros((cohort_pad,), valid.dtype)]
+                )
+            if have_faults and faults.has_pop:
+                # fold 105/1: per-slot injection coins over the popped cohort
+                fstate, eff = faults.on_pop(
+                    fstate, jax.random.fold_in(k_fault, 1), idx, valid
+                )
+                eff = cohort_layout(eff)
+            new_clock = jnp.maximum(clock, jnp.max(jnp.where(valid, t_ev, -jnp.inf)))
+            # an all-idle fleet inside availability gaps must not freeze the
+            # clock: with nothing in flight to pop, jump to the earliest
+            # window opening so availability can recover next step
+            new_clock = jnp.where(
+                valid.any(), new_clock,
+                jnp.maximum(new_clock, jnp.min(ev["next_avail"])),
+            )
+
+        with jax.named_scope("local_train"):
+            # --- local training from each client's dispatch-time model
+            disp_ver = cohort_layout(ev["disp_ver"][idx])
+            # versions older than the ring are trained from the oldest retained
+            # model; staleness for weighting still uses the true dispatch version
+            read_ver = jnp.clip(disp_ver, jnp.maximum(version - (H - 1), 0), version)
+            if have_faults and faults.has("replay"):
+                # stale replay: hit slots read an older retained version than
+                # they were dispatched (shift 0 elsewhere is exact identity on
+                # ints); the staleness *weight* below still sees the honest
+                # dispatch version — precisely the attack
+                read_ver = jnp.maximum(
+                    read_ver - eff.replay_shift,
+                    jnp.maximum(version - (H - 1), 0),
+                )
+            disp_params = cohort_layout(
+                jax.tree.map(lambda h: h[read_ver % H], state["hist"])
+            )
+            shards = cohort_layout(jax.tree.map(lambda a: a[idx], data))
+            keys = jax.random.split(k_local, B)
+            if cohort_pad:
+                # the first B keys must stay the exact draws of the unpadded
+                # engine (split(k, Bp) has a different prefix); padded slots
+                # reuse the last real key — their updates carry weight 0
+                keys = keys[jnp.minimum(jnp.arange(Bp), B - 1)]
+            lr = lr_fn(jnp.maximum(disp_ver, 0))
+            updated, losses = cohort_layout(
+                jax.vmap(local_update, in_axes=(0, 0, 0, 0))(
+                    disp_params, shards, keys, lr
+                )
+            )
+            if have_faults and (faults.has("scale") or faults.has("noise")):
+                # fold 105/2: corruption noise. Missed slots keep their exact
+                # input buffers (per-slot where inside corrupt_updates), so a
+                # rate-0 set is bitwise identity
+                updated = corrupt_updates(
+                    updated, disp_params, eff, jax.random.fold_in(k_fault, 2),
+                    faults.has("scale"), faults.has("noise"),
+                )
+            if collude_on:
+                # after corrupt: a coalition member's replacement is
+                # authoritative over any scale/noise it also drew. Keyless —
+                # the direction is a trace-time constant, the jitter rode
+                # the fault's own pop fold
+                updated = collude_updates(updated, disp_params, eff)
+
+        with jax.named_scope("aggregate"):
+            # --- buffered aggregation of deltas through the aggregator seam
+            succ = valid & ~ev["dropped"][idx]
+            if kill_on:
+                # mid-round dropout: the update never arrived — excluded from
+                # aggregation and from heartbeat contact below
+                succ = succ & ~eff.kill
+            if hb_timeout > 0:
+                # an update landing more than the timeout after its client's
+                # last contact looks dead to its tier coordinator: excluded
+                # from the reduction exactly like a dropped slot. All valid
+                # completions still count as contact (the client did return)
+                dark = succ & hb_mod.expired(
+                    hb["last_beat"][idx], t_ev, hb_timeout
+                )
+                succ = succ & ~dark
+                arrived = valid & ~eff.kill if kill_on else valid
+                hb = hb_mod.beat_at(hb, ev_mod.scatter_idx(idx, arrived), t_ev)
+            staleness = jnp.maximum(version - disp_ver, 0)
+            if have_def:
+                # fold 108: the defense tier's dedicated key (sub-folds
+                # 0 probation / 1 readmit coins). Every update that arrived
+                # (pre-exclusion succ) is scored — including probation
+                # clients — then post-transition suspects are excluded from
+                # the reduction through the exact seam heartbeat dark
+                # clients use, closing the detect->quarantine loop within
+                # the step
+                dstate, suspect, w_scale = defense.observe(
+                    dstate, jax.random.fold_in(k_sel, 108),
+                    updated, disp_params, idx, succ, staleness,
+                    losses=losses, ages=cohort_layout(sched["ages"][idx]),
+                    labels=cohort_layout(effects_hit(eff)) if sup_on else None,
+                )
+                succ = succ & ~cohort_layout(suspect[idx])
+            w = agg.weigh(succ, staleness)
+            if col_on:
+                # clique members keep a (discounted) vote rather than a
+                # binary exclusion: w_scale is exact 1.0 on clique-free
+                # slots, so a calm armed run multiplies by ones
+                w = w * w_scale
+            wsum = w.sum()
+            has = wsum > 0
+            denom = jnp.maximum(wsum, 1e-9)
+            if mtd_on:
+                params, agg_tel = aggregate_mtd(
+                    state["params"], updated, disp_params, w, idx,
+                    dstate["level"],
+                )
+            else:
+                params, agg_tel = aggregate(
+                    state["params"], updated, disp_params, w, idx
+                )
+            version = version + has.astype(jnp.int32)
+            hist = jax.tree.map(
+                lambda h, p: h.at[version % H].set(p), state["hist"], params
+            )
+            # NaN, not a fake 0.0 datapoint, when nothing was aggregated
+            mean_loss = jnp.where(has, jnp.sum(losses * w) / denom, jnp.nan)
+
+        with jax.named_scope("load_metric"):
+            # --- completed clients go idle; wall-clock AoI samples
+            # gaps are i.i.d. — draw only the B popped clients' worth
+            gaps = lat_mod.sample_avail_gap(k_gap, profile, B)
+            if cohort_pad:
+                gaps = jnp.concatenate(
+                    [gaps, jnp.zeros((cohort_pad,), gaps.dtype)]
+                )
             ev = {
                 **ev,
-                "t_done": jnp.where(
-                    retry, clock + rd_lat,
-                    jnp.where(give_up, jnp.inf, ev["t_done"]),
+                "next_avail": ev["next_avail"]
+                .at[ev_mod.scatter_idx(idx, valid)]
+                .set(new_clock + gaps, mode="drop"),
+            }
+            last_done = cohort_layout(ev["last_done"][idx])
+            x_wall = t_ev - last_done
+            wall_ok = succ & (last_done >= 0.0)
+            wall_okf = wall_ok.astype(jnp.float32)
+            ev = {
+                **ev,
+                "last_done": ev["last_done"]
+                .at[ev_mod.scatter_idx(idx, succ)]
+                .set(t_ev, mode="drop"),
+            }
+
+            stats = {
+                "wall_sx": stats["wall_sx"] + jnp.sum(jnp.where(wall_ok, x_wall, 0.0)),
+                "wall_sx2": stats["wall_sx2"]
+                + jnp.sum(jnp.where(wall_ok, x_wall**2, 0.0)),
+                "wall_cnt": stats["wall_cnt"] + wall_okf.sum(),
+                "ep_sx": ep_sx, "ep_sx2": ep_sx2, "ep_cnt": ep_cnt,
+                "stale_sum": stats["stale_sum"]
+                + jnp.sum(jnp.where(succ, staleness, 0).astype(jnp.float32)),
+                "stale_cnt": stats["stale_cnt"] + succ.astype(jnp.float32).sum(),
+                "stale_max": jnp.maximum(
+                    stats["stale_max"], jnp.max(jnp.where(succ, staleness, 0))
                 ),
-                "disp_ver": jnp.where(retry, version, ev["disp_ver"]),
+                "updates": stats["updates"] + succ.astype(jnp.float32).sum(),
+                "aggs": stats["aggs"] + has.astype(jnp.float32),
             }
-            rd = {
-                "t_disp": jnp.where(retry, clock, rd_t),
-                "retries": rd_cnt + retry.astype(jnp.int32),
+            if hb_timeout > 0:
+                stats["hb_expired"] = (
+                    state["stats"]["hb_expired"] + dark.astype(jnp.float32).sum()
+                )
+            if rd_on:
+                stats["redispatched"] = state["stats"]["redispatched"] + rd_retried
+                stats["rd_expired"] = state["stats"]["rd_expired"] + rd_expired
+            for s in agg.stat_names:
+                stats[f"agg_{s}"] = state["stats"][f"agg_{s}"] + agg_tel[s]
+            new_state = {
+                **state,
+                "params": params, "hist": hist, "sched": sched, "ev": ev,
+                "clock": new_clock, "version": version, "stats": stats,
             }
-            rd_retried = retry.astype(jnp.float32).sum()
-            rd_expired = exp.astype(jnp.float32).sum()
-
-        # --- pop the next B completions, advance the simulated clock
-        t_ev, idx, valid, ev = pop(ev)
-        if cohort_pad:
-            # pad the cohort to the mesh multiple with invalid slots:
-            # t=+inf/valid=False masks them out of the clock advance, the
-            # weights, the telemetry, and both scatters, exactly like an
-            # under-filled buffer slot
-            t_ev = jnp.concatenate(
-                [t_ev, jnp.full((cohort_pad,), jnp.inf, t_ev.dtype)]
-            )
-            idx = jnp.concatenate([idx, jnp.zeros((cohort_pad,), idx.dtype)])
-            valid = jnp.concatenate(
-                [valid, jnp.zeros((cohort_pad,), valid.dtype)]
-            )
-        if have_faults and faults.has_pop:
-            # fold 105/1: per-slot injection coins over the popped cohort
-            fstate, eff = faults.on_pop(
-                fstate, jax.random.fold_in(k_fault, 1), idx, valid
-            )
-            eff = cohort_layout(eff)
-        new_clock = jnp.maximum(clock, jnp.max(jnp.where(valid, t_ev, -jnp.inf)))
-        # an all-idle fleet inside availability gaps must not freeze the
-        # clock: with nothing in flight to pop, jump to the earliest
-        # window opening so availability can recover next step
-        new_clock = jnp.where(
-            valid.any(), new_clock,
-            jnp.maximum(new_clock, jnp.min(ev["next_avail"])),
-        )
-
-        # --- local training from each client's dispatch-time model
-        disp_ver = cohort_layout(ev["disp_ver"][idx])
-        # versions older than the ring are trained from the oldest retained
-        # model; staleness for weighting still uses the true dispatch version
-        read_ver = jnp.clip(disp_ver, jnp.maximum(version - (H - 1), 0), version)
-        if have_faults and faults.has("replay"):
-            # stale replay: hit slots read an older retained version than
-            # they were dispatched (shift 0 elsewhere is exact identity on
-            # ints); the staleness *weight* below still sees the honest
-            # dispatch version — precisely the attack
-            read_ver = jnp.maximum(
-                read_ver - eff.replay_shift,
-                jnp.maximum(version - (H - 1), 0),
-            )
-        disp_params = cohort_layout(
-            jax.tree.map(lambda h: h[read_ver % H], state["hist"])
-        )
-        shards = cohort_layout(jax.tree.map(lambda a: a[idx], data))
-        keys = jax.random.split(k_local, B)
-        if cohort_pad:
-            # the first B keys must stay the exact draws of the unpadded
-            # engine (split(k, Bp) has a different prefix); padded slots
-            # reuse the last real key — their updates carry weight 0
-            keys = keys[jnp.minimum(jnp.arange(Bp), B - 1)]
-        lr = lr_fn(jnp.maximum(disp_ver, 0))
-        updated, losses = cohort_layout(jax.vmap(local_update, in_axes=(0, 0, 0, 0))(
-            disp_params, shards, keys, lr
-        ))
-        if have_faults and (faults.has("scale") or faults.has("noise")):
-            # fold 105/2: corruption noise. Missed slots keep their exact
-            # input buffers (per-slot where inside corrupt_updates), so a
-            # rate-0 set is bitwise identity
-            updated = corrupt_updates(
-                updated, disp_params, eff, jax.random.fold_in(k_fault, 2),
-                faults.has("scale"), faults.has("noise"),
-            )
-        if collude_on:
-            # after corrupt: a coalition member's replacement is
-            # authoritative over any scale/noise it also drew. Keyless —
-            # the direction is a trace-time constant, the jitter rode
-            # the fault's own pop fold
-            updated = collude_updates(updated, disp_params, eff)
-
-        # --- buffered aggregation of deltas through the aggregator seam
-        succ = valid & ~ev["dropped"][idx]
-        if kill_on:
-            # mid-round dropout: the update never arrived — excluded from
-            # aggregation and from heartbeat contact below
-            succ = succ & ~eff.kill
-        if hb_timeout > 0:
-            # an update landing more than the timeout after its client's
-            # last contact looks dead to its tier coordinator: excluded
-            # from the reduction exactly like a dropped slot. All valid
-            # completions still count as contact (the client did return)
-            dark = succ & hb_mod.expired(
-                hb["last_beat"][idx], t_ev, hb_timeout
-            )
-            succ = succ & ~dark
-            arrived = valid & ~eff.kill if kill_on else valid
-            hb = hb_mod.beat_at(hb, ev_mod.scatter_idx(idx, arrived), t_ev)
-        staleness = jnp.maximum(version - disp_ver, 0)
-        if have_def:
-            # fold 108: the defense tier's dedicated key (sub-folds
-            # 0 probation / 1 readmit coins). Every update that arrived
-            # (pre-exclusion succ) is scored — including probation
-            # clients — then post-transition suspects are excluded from
-            # the reduction through the exact seam heartbeat dark
-            # clients use, closing the detect->quarantine loop within
-            # the step
-            dstate, suspect, w_scale = defense.observe(
-                dstate, jax.random.fold_in(k_sel, 108),
-                updated, disp_params, idx, succ, staleness,
-                losses=losses, ages=cohort_layout(sched["ages"][idx]),
-                labels=cohort_layout(effects_hit(eff)) if sup_on else None,
-            )
-            succ = succ & ~cohort_layout(suspect[idx])
-        w = agg.weigh(succ, staleness)
-        if col_on:
-            # clique members keep a (discounted) vote rather than a
-            # binary exclusion: w_scale is exact 1.0 on clique-free
-            # slots, so a calm armed run multiplies by ones
-            w = w * w_scale
-        wsum = w.sum()
-        has = wsum > 0
-        denom = jnp.maximum(wsum, 1e-9)
-        if mtd_on:
-            params, agg_tel = aggregate_mtd(
-                state["params"], updated, disp_params, w, idx,
-                dstate["level"],
-            )
-        else:
-            params, agg_tel = aggregate(
-                state["params"], updated, disp_params, w, idx
-            )
-        version = version + has.astype(jnp.int32)
-        hist = jax.tree.map(
-            lambda h, p: h.at[version % H].set(p), state["hist"], params
-        )
-        # NaN, not a fake 0.0 datapoint, when nothing was aggregated
-        mean_loss = jnp.where(has, jnp.sum(losses * w) / denom, jnp.nan)
-
-        # --- completed clients go idle; wall-clock AoI samples
-        # gaps are i.i.d. — draw only the B popped clients' worth
-        gaps = lat_mod.sample_avail_gap(k_gap, profile, B)
-        if cohort_pad:
-            gaps = jnp.concatenate(
-                [gaps, jnp.zeros((cohort_pad,), gaps.dtype)]
-            )
-        ev = {
-            **ev,
-            "next_avail": ev["next_avail"]
-            .at[ev_mod.scatter_idx(idx, valid)]
-            .set(new_clock + gaps, mode="drop"),
-        }
-        last_done = cohort_layout(ev["last_done"][idx])
-        x_wall = t_ev - last_done
-        wall_ok = succ & (last_done >= 0.0)
-        wall_okf = wall_ok.astype(jnp.float32)
-        ev = {
-            **ev,
-            "last_done": ev["last_done"]
-            .at[ev_mod.scatter_idx(idx, succ)]
-            .set(t_ev, mode="drop"),
-        }
-
-        stats = {
-            "wall_sx": stats["wall_sx"] + jnp.sum(jnp.where(wall_ok, x_wall, 0.0)),
-            "wall_sx2": stats["wall_sx2"] + jnp.sum(jnp.where(wall_ok, x_wall**2, 0.0)),
-            "wall_cnt": stats["wall_cnt"] + wall_okf.sum(),
-            "ep_sx": ep_sx, "ep_sx2": ep_sx2, "ep_cnt": ep_cnt,
-            "stale_sum": stats["stale_sum"]
-            + jnp.sum(jnp.where(succ, staleness, 0).astype(jnp.float32)),
-            "stale_cnt": stats["stale_cnt"] + succ.astype(jnp.float32).sum(),
-            "stale_max": jnp.maximum(
-                stats["stale_max"], jnp.max(jnp.where(succ, staleness, 0))
-            ),
-            "updates": stats["updates"] + succ.astype(jnp.float32).sum(),
-            "aggs": stats["aggs"] + has.astype(jnp.float32),
-        }
-        if hb_timeout > 0:
-            stats["hb_expired"] = (
-                state["stats"]["hb_expired"] + dark.astype(jnp.float32).sum()
-            )
-        if rd_on:
-            stats["redispatched"] = state["stats"]["redispatched"] + rd_retried
-            stats["rd_expired"] = state["stats"]["rd_expired"] + rd_expired
-        for s in agg.stat_names:
-            stats[f"agg_{s}"] = state["stats"][f"agg_{s}"] + agg_tel[s]
-        new_state = {
-            **state,
-            "params": params, "hist": hist, "sched": sched, "ev": ev,
-            "clock": new_clock, "version": version, "stats": stats,
-        }
-        if hb_timeout > 0:
-            new_state["hb"] = hb
-        if have_faults:
-            new_state["faults"] = fstate
-        if have_def:
-            new_state["defense"] = dstate
-        if rd_on:
-            new_state["rd"] = rd
-        if tiered:
-            new_state["tier_acc"] = update_tier_accum(
-                state["tier_acc"], send, assign_dev
-            )
+            if hb_timeout > 0:
+                new_state["hb"] = hb
+            if have_faults:
+                new_state["faults"] = fstate
+            if have_def:
+                new_state["defense"] = dstate
+            if rd_on:
+                new_state["rd"] = rd
+            if tiered:
+                new_state["tier_acc"] = update_tier_accum(
+                    state["tier_acc"], send, assign_dev
+                )
         state = constrain_state(new_state)
         aux = {
             "send": send,

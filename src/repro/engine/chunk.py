@@ -68,12 +68,10 @@ class ChunkRunner:
                 key = jax.random.fold_in(carry["k_run"], r)
                 inner = {k: v for k, v in carry.items() if k not in _RUNNER_KEYS}
                 inner, aux = step_fn(inner, key, data)
-                carry = {
-                    **inner,
-                    "k_run": carry["k_run"],
-                    "load_acc": update_selection_accum(
-                        carry["load_acc"], aux["send"]),
-                }
+                with jax.named_scope("load_metric"):
+                    load_acc = update_selection_accum(carry["load_acc"],
+                                                      aux["send"])
+                carry = {**inner, "k_run": carry["k_run"], "load_acc": load_acc}
                 ys = {k: aux[k] for k in aux_keys}
                 if with_history:
                     ys["send"] = aux["send"]

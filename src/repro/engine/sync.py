@@ -178,17 +178,19 @@ class SyncEngine:
             )
             out = {"params": params, "sched": sched}
             if assign is not None:
-                out["tier_acc"] = update_tier_accum(
-                    state["tier_acc"], selected, assign
-                )
+                with jax.named_scope("load_metric"):
+                    out["tier_acc"] = update_tier_accum(
+                        state["tier_acc"], selected, assign
+                    )
             if have_faults:
                 out["faults"] = fstate
             if have_def:
                 out["defense"] = dstate
             if stat_names:
-                out["agg_stats"] = {
-                    s: state["agg_stats"][s] + tel[s] for s in stat_names
-                }
+                with jax.named_scope("aggregate"):
+                    out["agg_stats"] = {
+                        s: state["agg_stats"][s] + tel[s] for s in stat_names
+                    }
             return out, {"send": selected, "loss": loss}
 
         self._chunk = ChunkRunner(scan_step, aux_keys=("loss",),
@@ -364,78 +366,81 @@ def _make_round_core(task: FLTask, cfg: RunConfig, policy: Policy, agg: Aggregat
     lr_fn = exponential_decay(cfg.lr0, cfg.lr_decay)
 
     def round_fn(params, sched_state, key, data, fstate=None, dstate=None):
-        k_sel, k_local = jax.random.split(key)
-        selected, sched_state = policy.step(sched_state, k_sel)
-        if have_def:
-            selected = selected & ~defense.blocked(dstate)
-        idx, mask = cohort_indices(selected, width)
-        keys = jax.random.split(k_local, width)
-        if cohort_pad:
-            # pad to the mesh multiple with weight-0 slots; real slots
-            # keep the exact unpadded key draws (split(k, wp) has a
-            # different prefix than split(k, width))
-            idx = jnp.concatenate([idx, jnp.zeros((cohort_pad,), idx.dtype)])
-            mask = jnp.concatenate([mask, jnp.zeros((cohort_pad,), mask.dtype)])
-            keys = keys[jnp.minimum(jnp.arange(wp), width - 1)]
-        eff = None
-        if have_faults:
-            k_fault = jax.random.fold_in(k_sel, 105)
-            fstate, eff = faults.on_pop(
-                fstate, jax.random.fold_in(k_fault, 1), idx, mask > 0
+        with jax.named_scope("admission"):
+            k_sel, k_local = jax.random.split(key)
+            selected, sched_state = policy.step(sched_state, k_sel)
+            if have_def:
+                selected = selected & ~defense.blocked(dstate)
+            idx, mask = cohort_indices(selected, width)
+            keys = jax.random.split(k_local, width)
+            if cohort_pad:
+                # pad to the mesh multiple with weight-0 slots; real slots
+                # keep the exact unpadded key draws (split(k, wp) has a
+                # different prefix than split(k, width))
+                idx = jnp.concatenate([idx, jnp.zeros((cohort_pad,), idx.dtype)])
+                mask = jnp.concatenate([mask, jnp.zeros((cohort_pad,), mask.dtype)])
+                keys = keys[jnp.minimum(jnp.arange(wp), width - 1)]
+        with jax.named_scope("local_train"):
+            eff = None
+            if have_faults:
+                k_fault = jax.random.fold_in(k_sel, 105)
+                fstate, eff = faults.on_pop(
+                    fstate, jax.random.fold_in(k_fault, 1), idx, mask > 0
+                )
+                eff = cohort_layout(eff)
+            shards = cohort_layout(jax.tree.map(lambda a: a[idx], data))
+            lr = lr_fn(sched_state["round"] - 1)
+            # the cohort axis of the global params is a lazy vmap broadcast —
+            # no (width, ...) copies are materialized; aggregators see the
+            # unstacked global tree as ``bases`` and broadcast in their deltas
+            updated, losses = cohort_layout(
+                jax.vmap(local_update, in_axes=(None, 0, 0, None))(
+                    params, shards, keys, lr
+                )
             )
-            eff = cohort_layout(eff)
-        shards = cohort_layout(jax.tree.map(lambda a: a[idx], data))
-        lr = lr_fn(sched_state["round"] - 1)
-        # the cohort axis of the global params is a lazy vmap broadcast —
-        # no (width, ...) copies are materialized; aggregators see the
-        # unstacked global tree as ``bases`` and broadcast in their deltas
-        updated, losses = cohort_layout(
-            jax.vmap(local_update, in_axes=(None, 0, 0, None))(
-                params, shards, keys, lr
+            if corrupt_on:
+                updated = corrupt_updates(
+                    updated, params, eff, jax.random.fold_in(k_fault, 2),
+                    faults.has("scale"), faults.has("noise"),
+                )
+            if collude_on:
+                # after corrupt: the coalition's replacement is authoritative
+                updated = collude_updates(updated, params, eff)
+        with jax.named_scope("aggregate"):
+            valid = mask > 0
+            if kill_on:
+                # a dropped client's update never reaches the server: weight 0
+                valid = valid & ~eff.kill
+            if have_def:
+                # fold 108 (same schedule as the async engine); staleness is
+                # identically zero in a sync round
+                ages = (cohort_layout(sched_state["ages"][idx])
+                        if "ages" in sched_state else None)
+                dstate, suspect, w_scale = defense.observe(
+                    dstate, jax.random.fold_in(k_sel, 108),
+                    updated, params, idx, valid, jnp.zeros_like(idx),
+                    losses=losses, ages=ages,
+                    labels=cohort_layout(effects_hit(eff)) if sup_on else None,
+                )
+                valid = valid & ~cohort_layout(suspect[idx])
+            # sync cohorts are never stale: staleness is identically zero
+            w = agg.weigh(valid, jnp.zeros_like(idx))
+            if col_on:
+                # exact 1.0 on clique-free slots: calm armed rounds multiply
+                # the weights by ones
+                w = w * w_scale
+            if mtd_on:
+                params, tel = aggregate_mtd(
+                    params, updated, params, w, idx, dstate["level"]
+                )
+            else:
+                params, tel = aggregate(params, updated, params, w, idx)
+            wsum = w.sum()
+            # NaN, not a fake near-0 datapoint, when nobody was selected
+            # (matching the async engine's empty-buffer convention)
+            mean_loss = jnp.where(
+                wsum > 0, jnp.sum(losses * w) / jnp.maximum(wsum, 1.0), jnp.nan
             )
-        )
-        if corrupt_on:
-            updated = corrupt_updates(
-                updated, params, eff, jax.random.fold_in(k_fault, 2),
-                faults.has("scale"), faults.has("noise"),
-            )
-        if collude_on:
-            # after corrupt: the coalition's replacement is authoritative
-            updated = collude_updates(updated, params, eff)
-        valid = mask > 0
-        if kill_on:
-            # a dropped client's update never reaches the server: weight 0
-            valid = valid & ~eff.kill
-        if have_def:
-            # fold 108 (same schedule as the async engine); staleness is
-            # identically zero in a sync round
-            ages = (cohort_layout(sched_state["ages"][idx])
-                    if "ages" in sched_state else None)
-            dstate, suspect, w_scale = defense.observe(
-                dstate, jax.random.fold_in(k_sel, 108),
-                updated, params, idx, valid, jnp.zeros_like(idx),
-                losses=losses, ages=ages,
-                labels=cohort_layout(effects_hit(eff)) if sup_on else None,
-            )
-            valid = valid & ~cohort_layout(suspect[idx])
-        # sync cohorts are never stale: staleness is identically zero
-        w = agg.weigh(valid, jnp.zeros_like(idx))
-        if col_on:
-            # exact 1.0 on clique-free slots: calm armed rounds multiply
-            # the weights by ones
-            w = w * w_scale
-        if mtd_on:
-            params, tel = aggregate_mtd(
-                params, updated, params, w, idx, dstate["level"]
-            )
-        else:
-            params, tel = aggregate(params, updated, params, w, idx)
-        wsum = w.sum()
-        # NaN, not a fake near-0 datapoint, when nobody was selected
-        # (matching the async engine's empty-buffer convention)
-        mean_loss = jnp.where(
-            wsum > 0, jnp.sum(losses * w) / jnp.maximum(wsum, 1.0), jnp.nan
-        )
         return params, sched_state, selected, mean_loss, fstate, dstate, tel
 
     return round_fn
