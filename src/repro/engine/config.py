@@ -445,7 +445,9 @@ class RoundRecord:
     """One evaluated round / server step, identical for both engines.
 
     ``clock``/``version``/``buffer_fill`` are simulator quantities and stay
-    None under the sync engine.
+    None under the sync engine; ``trained_slots`` (the cohort slots the
+    round's local training ran) is the sync engine's and stays None under
+    the async one.
     """
 
     round: int
@@ -455,6 +457,7 @@ class RoundRecord:
     clock: Optional[float] = None
     version: Optional[int] = None
     buffer_fill: Optional[int] = None
+    trained_slots: Optional[int] = None
 
 
 @dataclasses.dataclass

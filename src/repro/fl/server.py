@@ -16,9 +16,13 @@ import jax.numpy as jnp
 def cohort_indices(selected: jnp.ndarray, width: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(indices (width,), weights (width,)) from an (n,) bool mask.
 
-    Overflow beyond ``width`` is dropped (rare: the default width is
-    k + 4 sigma of the binomial cohort size); padding entries point at
-    client 0 with weight 0.
+    The selected clients are packed, in ascending order, into a prefix of
+    the slots: the first min(count, width) slots are valid and the rest
+    padding, so the sync round's group loop (``engine.sync._train_groups``)
+    trains only the groups that hold a selected client. Overflow beyond
+    ``width`` is dropped (rare: the default width is k + 4 sigma of the
+    binomial cohort size); padding entries point at client 0 with
+    weight 0.
     """
     idx = jnp.nonzero(selected, size=width, fill_value=-1)[0]
     w = (idx >= 0).astype(jnp.float32)
