@@ -9,7 +9,8 @@ own, found by name:
 - ``bench/traffic/<traffic>.json``: the ``RunConfig`` fields that shape the
   traffic (buffer, eval cadence, steps per host dispatch);
 - ``bench/cells/<cell>.json``: what the correctness check compares over
-  (how many steps) and the limit of every number it compares.
+  (how many steps), the limit of every number it compares, and, where
+  the cell fixes them, the window's length in steps and its work.
 
 Nothing here knows a cell by name, so a later cell adds files and edits
 none.
@@ -58,6 +59,34 @@ class Cell:
                              f"a whole number of {chunk}-step chunks within "
                              f"one {self.period}-step eval period")
         return steps
+
+    @property
+    def window_steps(self):
+        """The window's length in steps where the cell fixes it (whole eval
+        periods), so every run of a seed runs the same steps; None where
+        the window is sized from ``--seconds``."""
+        steps = self.check.get("window_steps")
+        if steps is None:
+            return None
+        if steps <= 0 or steps % self.period:
+            raise ValueError(f"cell {self.name}: window_steps {steps} is not "
+                             f"a whole number of {self.period}-step eval "
+                             "periods")
+        return int(steps)
+
+    @property
+    def window_work(self):
+        """Where the cell fixes the window's work, ``{"group": g, "slots":
+        s}``: every run seed it draws trains ``s`` cohort slots over the
+        window's sync rounds, each round's cohort counted in whole groups
+        of ``g``; else None."""
+        work = self.check.get("window_work")
+        if work is None:
+            return None
+        if self.window_steps is None or self.config["run"]["mode"] != "sync":
+            raise ValueError(f"cell {self.name}: window_work needs sync "
+                             "rounds and window_steps")
+        return {"group": int(work["group"]), "slots": int(work["slots"])}
 
     def run_config(self, seed: int, rounds: int):
         from repro.engine import RunConfig

@@ -9,9 +9,9 @@ set is standardized by its own mean and standard deviation. Labels are
 uniform over the classes. Clients hold i.i.d. samples, as the IID
 partition gives them.
 
-Here every draw is a ``jax.random`` call in one jitted program, so a
-fleet of millions of clients is made in well under a second and never
-passes through the host. Across several devices each device makes its own
+Every draw is a ``jax.random`` call in a jitted program that makes one
+block of clients, so a fleet of millions of clients is made in seconds
+and never passes through the host. Across several devices each device makes its own
 block of clients (``shard_map``), so the fleet's data never sits whole on
 one device.
 """
@@ -38,6 +38,11 @@ class ImageData:
     y: jax.Array  # (n_clients, per_client) int32
     test_x: jax.Array  # (test, H, W, C) float32, standardized
     test_y: jax.Array  # (test,) int32
+
+    @property
+    def test(self):
+        """The test set as ``paper_cnn.eval_loss`` reads it."""
+        return self.test_x, self.test_y
 
 
 def prototypes(key, classes: int, channels: int) -> jax.Array:
@@ -66,19 +71,29 @@ def images(key, protos, n: int, size: int, difficulty: float):
 
 def _clients(key, protos, clients: int, per_client: int, size: int,
              difficulty: float):
-    """``clients`` clients' samples, made block by block."""
+    """``clients`` clients' samples, made block by block (block ``i`` from
+    ``fold_in(key, i)``) and joined by one concatenate. Outside ``jit``
+    each block is a jitted call of its own: stacked inside one program,
+    the blocks take a padded TPU layout several times the fleet's size."""
     target = max(BLOCK_IMAGES // per_client, 1)
     block = max(d for d in range(1, min(target, clients) + 1)
                 if clients % d == 0)
+    one = _block(block, per_client, size, difficulty)
+    xs, ys = zip(*(one(jax.random.fold_in(key, i), protos)
+                   for i in range(clients // block)))
+    return jnp.concatenate(xs), jnp.concatenate(ys)
 
-    def one(i):
-        x, y = images(jax.random.fold_in(key, i), protos, block * per_client,
-                      size, difficulty)
+
+def _block(block: int, per_client: int, size: int, difficulty: float):
+    """One block of ``block`` clients: ``(key, protos) -> (x, y)``."""
+
+    @jax.jit
+    def one(key, protos):
+        x, y = images(key, protos, block * per_client, size, difficulty)
         return (x.reshape((block, per_client) + x.shape[1:]),
                 y.reshape(block, per_client))
 
-    x, y = jax.lax.map(one, jnp.arange(clients // block))
-    return x.reshape((clients,) + x.shape[2:]), y.reshape(clients, per_client)
+    return one
 
 
 @jax.jit
@@ -116,9 +131,9 @@ def make(seed_key, dataset: dict, n_clients: int, per_client: int,
                         int(dataset["classes"]), ch)
     k_train = jax.random.fold_in(seed_key, 1)
     if shards == 1:
-        gen = jax.jit(functools.partial(
+        gen = functools.partial(
             _clients, clients=n_clients, per_client=per_client, size=size,
-            difficulty=difficulty))
+            difficulty=difficulty)
     else:
         if n_clients % shards:
             raise ValueError(f"{shards} shards do not divide {n_clients} "

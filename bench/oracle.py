@@ -22,6 +22,9 @@ The reference then follows the same steps and ``compare`` reads:
   worst leaf: |‖Δ_prog‖ - ‖Δ_ref‖| over the larger of ‖Δ_ref‖ and the
   median leaf's ‖Δ_ref‖. Leaves whose reference change is under a
   thousandth of the median leaf's are left out (none of the CNN's are);
+- ``update_dist``: the same change, by the worst leaf, ‖Δ_prog - Δ_ref‖
+  over the same scale: where the norms agree, as when the cohort trained
+  other clients' i.i.d. examples, the direction can still differ;
 - ``param_gap``: the params after the compared steps, by the worst leaf:
   ‖p_prog - p_ref‖ over the larger of ‖p_ref‖ and the median leaf's
   ‖p_ref‖. Parameters held in bfloat16 read the rounding of every
@@ -109,15 +112,31 @@ def _count(a, b, rel=REL) -> int:
     return int(np.sum(_rel(a, b) > rel))
 
 
-def leaf_gap(dp: List[np.ndarray], dr: List[np.ndarray]) -> float:
-    """Worst leaf's gap of norms (see the module docstring)."""
-    np_ = np.array([np.linalg.norm(np.asarray(x, np.float64)) for x in dp])
-    nr = np.array([np.linalg.norm(np.asarray(x, np.float64)) for x in dr])
+def _norms(leaves) -> np.ndarray:
+    return np.array([np.linalg.norm(np.asarray(x, np.float64))
+                     for x in leaves])
+
+
+def _worst(gaps: np.ndarray, nr: np.ndarray) -> float:
+    """The largest of ``gaps`` over the larger of each leaf's reference norm
+    ``nr`` and the median leaf's, leaving out leaves under a thousandth of
+    the median."""
     med = float(np.median(nr))
     keep = nr >= 1e-3 * med
     if not keep.any():
         return float("inf")
-    return float(np.max(np.abs(np_ - nr)[keep] / np.maximum(nr, med)[keep]))
+    return float(np.max(gaps[keep] / np.maximum(nr, med)[keep]))
+
+
+def leaf_gap(dp: List[np.ndarray], dr: List[np.ndarray]) -> float:
+    """Worst leaf's gap of norms (see the module docstring)."""
+    nr = _norms(dr)
+    return _worst(np.abs(_norms(dp) - nr), nr)
+
+
+def leaf_dist(dp: List[np.ndarray], dr: List[np.ndarray]) -> float:
+    """Worst leaf's distance (see the module docstring)."""
+    return _worst(_norms([a - b for a, b in zip(dp, dr)]), _norms(dr))
 
 
 def param_gap(pp, pr) -> float:
@@ -130,7 +149,7 @@ def param_gap(pp, pr) -> float:
     return float(np.max(dist / np.maximum(nr, np.median(nr))))
 
 
-def _change(after, before) -> List[np.ndarray]:
+def change(after, before) -> List[np.ndarray]:
     return [np.asarray(a, np.float64) - np.asarray(b, np.float64)
             for a, b in zip(jax.tree.leaves(after), jax.tree.leaves(before))]
 
@@ -163,11 +182,12 @@ def compare(got: Dict, ref: Dict) -> Dict[str, float]:
             + _count(fg["t_done"], fr["t_done"])
             + _count(fg["disp_ver"], fr["disp_ver"], 0)
             + _count(fg["last_done"], fr["last_done"]))
-    p0 = ref["params0"]
+    dp = change(got["params"][0], got["params0"])
+    dr = change(ref["params"][0], ref["params0"])
     out.update({
         "loss_gap": _loss_gap(got["loss"], ref["loss"]),
-        "update_gap": leaf_gap(_change(got["params"][0], got["params0"]),
-                               _change(ref["params"][0], p0)),
+        "update_gap": leaf_gap(dp, dr),
+        "update_dist": leaf_dist(dp, dr),
         "param_gap": param_gap(got["params"][-1], ref["params"][-1]),
         "eval_gap": float(np.max(_rel(got["eval"], ref["eval"]))),
     })

@@ -2,8 +2,12 @@
 
 It imports nothing of the program and takes nothing the program made: it
 draws its own parameters, selections, latencies and local batches from
-the run's seed with the same ``jax.random`` calls the semantics name, and
-computes every matrix product at ``Precision.HIGHEST``. Written for
+the run's seed with the same ``jax.random`` calls the semantics name. The
+model (parameters, a batch's loss, the eval) is the configuration's model
+module's (``bench/models``), whose matrix products run at JAX's default
+precision, the one both configurations state for the program's; here are
+only the federated semantics, whose weighted sums are float32
+(``Precision.HIGHEST``). Written for
 reading, not speed: straightforward ``jax.numpy``, one client at a time
 inside a block (``vmap`` over a block of clients, ``scan`` over blocks,
 so only one block of parameter copies is alive).
@@ -29,6 +33,7 @@ of three splits of ``PRNGKey(seed)``; step ``r`` uses
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, List
 
@@ -42,58 +47,11 @@ CLIENT_BLOCK = 125
 I32_MAX = np.iinfo(np.int32).max
 
 
-# ---------------------------------------------------------------- model
+# ---------------------------------------------------------------- training
 
 
-def cnn_init(key, w: dict, dtype) -> Dict:
-    """He-normal weights, zero biases (McMahan et al.'s CNN)."""
-    ks = jax.random.split(key, 4)
-    c1, c2 = w["conv_channels"]
-    kk, s = w["kernel"], w["image_size"] // 4
-    flat = s * s * c2
-
-    def he(k, shape, fan_in):
-        return jax.random.normal(k, shape) * (2.0 / fan_in) ** 0.5
-
-    p = {
-        "conv1": {"w": he(ks[0], (kk, kk, w["channels"], c1),
-                          kk * kk * w["channels"]), "b": jnp.zeros((c1,))},
-        "conv2": {"w": he(ks[1], (kk, kk, c1, c2), kk * kk * c1),
-                  "b": jnp.zeros((c2,))},
-        "fc1": {"w": he(ks[2], (flat, w["fc_width"]), flat),
-                "b": jnp.zeros((w["fc_width"],))},
-        "fc2": {"w": he(ks[3], (w["fc_width"], w["num_classes"]),
-                        w["fc_width"]), "b": jnp.zeros((w["num_classes"],))},
-    }
-    return jax.tree.map(lambda a: a.astype(dtype), p)
-
-
-def cnn_forward(p, x):
-    def conv(x, q):
-        y = jax.lax.conv_general_dilated(
-            x, q["w"], (1, 1), "SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=HIGHEST)
-        return y + q["b"]
-
-    def pool(x):
-        return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max, (1, 2, 2, 1),
-                                     (1, 2, 2, 1), "VALID")
-
-    x = pool(jax.nn.relu(conv(x, p["conv1"])))
-    x = pool(jax.nn.relu(conv(x, p["conv2"])))
-    x = x.reshape(x.shape[0], -1)
-    x = jax.nn.relu(jnp.dot(x, p["fc1"]["w"], precision=HIGHEST)
-                    + p["fc1"]["b"])
-    return jnp.dot(x, p["fc2"]["w"], precision=HIGHEST) + p["fc2"]["b"]
-
-
-def xent(p, x, y):
-    logp = jax.nn.log_softmax(cnn_forward(p, x))
-    return -jnp.take_along_axis(logp, y[:, None], axis=-1).mean()
-
-
-def local_train(p, xs, ys, key, lr, epochs: int, batch: int):
-    """``epochs`` passes of SGD over one client's examples."""
+def local_train(loss_fn, p, xs, ys, key, lr, epochs: int, batch: int):
+    """``epochs`` passes of SGD on ``loss_fn`` over one client's examples."""
     examples = xs.shape[0]
     nb, bs = max(examples // batch, 1), min(batch, examples)
     perms = jax.vmap(
@@ -101,27 +59,23 @@ def local_train(p, xs, ys, key, lr, epochs: int, batch: int):
     )(jax.random.split(key, epochs)).reshape(epochs * nb, bs)
 
     def sgd(p, idx):
-        loss, g = jax.value_and_grad(xent)(p, xs[idx], ys[idx])
+        loss, g = jax.value_and_grad(loss_fn)(p, xs[idx], ys[idx])
         return jax.tree.map(lambda a, b: (a - lr * b).astype(a.dtype), p, g), loss
 
     p, losses = jax.lax.scan(sgd, p, perms)
     return p, losses.mean()
 
 
-def evaluate(p, tx, ty, batch: int = 500):
-    """Mean loss over the test set in batches of ``batch`` (a last
-    partial batch is left out)."""
-    bs = min(batch, tx.shape[0])
-    nb = max(tx.shape[0] // bs, 1)
-    xb = tx[:nb * bs].reshape((nb, bs) + tx.shape[1:])
-    yb = ty[:nb * bs].reshape(nb, bs)
+def _cast(a, dtype):
+    """Floating arrays in ``dtype``; integer ones (labels, tokens) as
+    they are."""
+    return a.astype(dtype) if jnp.issubdtype(a.dtype, jnp.floating) else a
 
-    def one(carry, b):
-        logp = jax.nn.log_softmax(cnn_forward(p, b[0]).astype(jnp.float32))
-        return carry - jnp.take_along_axis(logp, b[1][:, None], axis=-1).sum(), None
 
-    total, _ = jax.lax.scan(one, jnp.zeros((), jnp.float32), (xb, yb))
-    return total / (nb * bs)
+@functools.partial(jax.jit, static_argnums=1)
+def _rows(a, dtype):
+    """One row per client: ``(n, per_client, ...)`` -> ``(n, -1)``."""
+    return _cast(a.reshape(a.shape[0], -1), dtype)
 
 
 # ---------------------------------------------------------------- admission
@@ -158,6 +112,45 @@ def cohort_width(n: int, k: int) -> int:
     return min(n, int(k + 4 * math.sqrt(n * q * (1 - q))) + 1)
 
 
+def run_keys(seed, n: int, m: int, pi):
+    """A run seed's (init key, starting ages, run key): the ages are drawn
+    from the chain's stationary law ``pi``."""
+    k_init, k_policy, k_run = jax.random.split(jax.random.PRNGKey(seed), 3)
+    ages = jax.random.choice(k_policy, m + 1, shape=(n,), p=pi)
+    return k_init, ages.astype(jnp.int32), k_run
+
+
+def want(k_sel, ages, p, m: int):
+    """The clients that want the model this step."""
+    return jax.random.uniform(k_sel, ages.shape) < p[jnp.minimum(ages, m)]
+
+
+def cohort_sizes(config: dict, traffic: dict, run_seeds, steps: int):
+    """Each run seed's sync cohort size (clients that want the model, at
+    most the cohort width) in each of its first ``steps`` rounds, as an
+    array (seeds, steps). Admission alone decides it: training does not
+    feed back into it."""
+    run = {**config["run"], **traffic["run"]}
+    n, k, m = run["n_clients"], run["k"], run["m"]
+    p = markov_probs(n, k, m)
+    pi = jnp.asarray(stationary(p).astype(np.float32))
+    p, width = jnp.asarray(p), cohort_width(n, k)
+
+    def one(seed):
+        _, ages, k_run = run_keys(seed, n, m, pi)
+
+        def step(ages, r):
+            k_sel, _ = jax.random.split(jax.random.fold_in(k_run, r))
+            sel = want(k_sel, ages, p, m)
+            return ((ages + 1) * (1 - sel.astype(jnp.int32)),
+                    jnp.minimum(sel.sum(), width))
+
+        return jax.lax.scan(step, ages, jnp.arange(steps))[1]
+
+    seeds = jnp.asarray(np.asarray(run_seeds, np.int64), jnp.int32)
+    return np.asarray(jax.jit(jax.vmap(one))(seeds))
+
+
 def _accumulate_gaps(acc, sel, r):
     """Selection gaps X = r - (step of the client's last selection)."""
     has = sel & (acc["last_sel"] >= 0)
@@ -170,55 +163,29 @@ def _accumulate_gaps(acc, sel, r):
     }
 
 
-def _block_train(params_of, data, idx, keys, lrs, weights, epochs, batch):
-    """Weighted sum over the cohort of (trained params - start params) and
-    of the local losses, training ``CLIENT_BLOCK`` clients at a time.
-    ``params_of(j)`` is slot ``j``'s start params."""
-    width = idx.shape[0]
-    blk = min(CLIENT_BLOCK, width)
-    pad = -width % blk
-    slots = jnp.arange(width + pad).reshape(-1, blk)
-
-    def one(j):
-        j = jnp.minimum(j, width - 1)
-        start = params_of(j)
-        got, loss = local_train(start, data["x"][idx[j]], data["y"][idx[j]],
-                                keys[j], lrs[j], epochs, batch)
-        return jax.tree.map(lambda a, b: a - b, got, start), loss
-
-    def body(acc, js):
-        deltas, losses = jax.vmap(one)(js)
-        w = jnp.where(js < width, weights[jnp.minimum(js, width - 1)], 0.0)
-        dsum = jax.tree.map(
-            lambda s, d: s + jnp.tensordot(w.astype(d.dtype), d, axes=1,
-                                           precision=HIGHEST), acc[0], deltas)
-        return (dsum, acc[1] + (w * losses.astype(jnp.float32)).sum()), None
-
-    zero = jax.tree.map(jnp.zeros_like, params_of(0))
-    (dsum, lsum), _ = jax.lax.scan(body, (zero, jnp.zeros((), jnp.float32)),
-                                   slots)
-    return dsum, lsum
-
-
 # ---------------------------------------------------------------- runs
 
 
 class Reference:
     """One configuration's reference run from a seed.
 
-    ``dtype`` is the type parameters, data and arithmetic are held in:
-    float32 for the reference, bfloat16 for its control."""
+    ``model`` is the configuration's model module (``bench.models``): its
+    ``init``, ``loss`` and ``eval_loss``. ``dtype`` is the type parameters,
+    data and arithmetic are held in: float32 for the reference, bfloat16
+    for its control."""
 
-    def __init__(self, config: dict, traffic: dict, data, seed: int,
+    def __init__(self, model, config: dict, traffic: dict, data, seed: int,
                  dtype=jnp.float32):
+        self.model = model
         self.run = {**config["run"], **traffic["run"]}
-        self.widths = config["widths"]
         self.latency = config.get("latency", {})
         self.dtype = dtype
         # arguments of the jitted programs: closed over, XLA would embed
-        # the fleet's data as a constant
-        self.data = {"x": data.x.astype(dtype), "y": data.y}
-        self.test = (data.test_x.astype(dtype), data.test_y)
+        # the fleet's data as a constant. One row per client, so a block's
+        # gather reads its rows and relays out nothing else
+        self.data = {"x": _rows(data.x, dtype), "y": _rows(data.y, dtype)}
+        self.shapes = {k: getattr(data, k).shape[1:] for k in ("x", "y")}
+        self.test = jax.tree.map(lambda a: _cast(a, dtype), data.test)
         run = self.run
         if run["policy"] != "markov":
             raise ValueError(f"the reference follows the markov policy only, "
@@ -227,22 +194,52 @@ class Reference:
         p = markov_probs(self.n, self.k, self.m)
         self.p = jnp.asarray(p)
         self.pi = jnp.asarray(stationary(p).astype(np.float32))
-        k_init, k_policy, self.k_run = jax.random.split(
-            jax.random.PRNGKey(seed), 3)
-        self.params0 = cnn_init(k_init, self.widths, dtype)
-        self.ages0 = jax.random.choice(k_policy, self.m + 1, shape=(self.n,),
-                                       p=self.pi).astype(jnp.int32)
+        k_init, self.ages0, self.k_run = run_keys(seed, self.n, self.m,
+                                                   self.pi)
+        self.params0 = model.init(k_init, config, dtype)
         self.sync = run.get("mode", "sync") == "sync"
         self._chunk = jax.jit(self._sync_chunk if self.sync
                               else self._async_chunk, static_argnums=3)
-        self._eval = jax.jit(evaluate)
+        self._eval = jax.jit(model.eval_loss)
+
+    def _train(self, params_of, data, idx, keys, lrs, weights):
+        """Weighted sum over the cohort of (trained params - start params)
+        and of the local losses, training ``CLIENT_BLOCK`` clients at a
+        time. ``params_of(j)`` is slot ``j``'s start params; ``data`` holds
+        each client's examples as one row."""
+        width = idx.shape[0]
+        blk = min(CLIENT_BLOCK, width)
+        pad = -width % blk
+        slots = jnp.arange(width + pad).reshape(-1, blk)
+
+        def one(j):
+            j = jnp.minimum(j, width - 1)
+            start = params_of(j)
+            xs, ys = (data[k][idx[j]].reshape(self.shapes[k]) for k in ("x", "y"))
+            got, loss = local_train(self.model.loss, start, xs, ys, keys[j],
+                                    lrs[j], self.run["local_epochs"],
+                                    self.run["batch_size"])
+            return jax.tree.map(lambda a, b: a - b, got, start), loss
+
+        def body(acc, js):
+            deltas, losses = jax.vmap(one)(js)
+            w = jnp.where(js < width, weights[jnp.minimum(js, width - 1)], 0.0)
+            dsum = jax.tree.map(
+                lambda s, d: s + jnp.tensordot(w.astype(d.dtype), d, axes=1,
+                                               precision=HIGHEST), acc[0], deltas)
+            return (dsum, acc[1] + (w * losses.astype(jnp.float32)).sum()), None
+
+        zero = jax.tree.map(jnp.zeros_like, params_of(0))
+        (dsum, lsum), _ = jax.lax.scan(body, (zero, jnp.zeros((), jnp.float32)),
+                                       slots)
+        return dsum, lsum
 
     def lr(self, t):
         return (jnp.asarray(self.run["lr0"], jnp.float32)
                 * self.run["lr_decay"] ** t.astype(jnp.float32))
 
     def _want(self, k_sel, ages):
-        return jax.random.uniform(k_sel, (self.n,)) < self.p[jnp.minimum(ages, self.m)]
+        return want(k_sel, ages, self.p, self.m)
 
     def init_state(self) -> Dict:
         st = {
@@ -278,7 +275,6 @@ class Reference:
 
     # one sync round (FedAvg over the padded cohort's real members)
     def _sync_chunk(self, st, data, r0, length):
-        run = self.run
         width = cohort_width(self.n, self.k)
 
         def step(st, r):
@@ -290,9 +286,7 @@ class Reference:
             keys = jax.random.split(k_local, width)
             lrs = jnp.broadcast_to(self.lr(r), (width,))
             g = st["params"]
-            dsum, lsum = _block_train(lambda j: g, data, idx, keys, lrs,
-                                      mask, run["local_epochs"],
-                                      run["batch_size"])
+            dsum, lsum = self._train(lambda j: g, data, idx, keys, lrs, mask)
             wsum = mask.sum()
             new = jax.tree.map(
                 lambda a, d: jnp.where(wsum > 0, a + (d / jnp.maximum(wsum, 1.0)).astype(a.dtype), a),
@@ -348,9 +342,9 @@ class Reference:
             w = succ.astype(jnp.float32) * (1.0 + stale) ** (
                 -run.get("aggregator_kwargs", {}).get("staleness_exp", 0.5))
             hist = st["hist"]
-            dsum, lsum = _block_train(
+            dsum, lsum = self._train(
                 lambda j: jax.tree.map(lambda h: h[read[j]], hist), data,
-                idx, keys, lrs, w, run["local_epochs"], run["batch_size"])
+                idx, keys, lrs, w)
             wsum = w.sum()
             has = wsum > 0
             params = jax.tree.map(
@@ -392,7 +386,7 @@ class Reference:
             st, ys = self._chunk(st, self.data, r0, chunk)
             ys_all.append(jax.device_get(ys))
             out["params"].append(jax.device_get(st["params"]))
-        out["eval"] = [float(self._eval(st["params"], *self.test))]
+        out["eval"] = [float(self._eval(st["params"], self.test))]
         for key in ys_all[0]:
             out[key] = np.concatenate([y[key] for y in ys_all])
         out["params0"] = jax.device_get(self.params0)

@@ -4,14 +4,15 @@ JSON line.
 
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Set-up makes the cell's data on the device from the seed, builds the
-program's task and engine (``repro.fl.make_cnn_task``,
-``repro.engine.make_engine``) and drives ``repro.engine.run_engine`` twice:
-first over the cell's compared steps, which compiles every program the
-window uses and is what the correctness check reads, then over one eval
-period, whose time sets the window's length. The window is one
-``run_engine`` call over a whole number of eval periods, about
-``--seconds`` long; ``steps_per_s`` is its steps over its wall time.
+Set-up makes the cell's data on the device from the seed and builds the
+program's task, both through the configuration's model module
+(``bench/models``), and the engine (``repro.engine.make_engine``). It
+drives ``repro.engine.run_engine`` over the cell's compared steps, which
+compiles every program the window uses and is what the correctness check
+reads. The window is one ``run_engine`` call over a whole number of eval
+periods: the cell's ``window_steps`` where it fixes them, else about
+``--seconds`` long, as sized by the time of one more eval period in
+set-up. ``steps_per_s`` is its steps over its wall time.
 
 With ``--trace 1`` the window runs under the profiler, with host spans
 around the engine's hooks, and the line carries the per-layer metrics.
@@ -30,7 +31,6 @@ import argparse  # noqa: E402
 import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import hashlib  # noqa: E402
-import importlib  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -49,11 +49,39 @@ class NoChip(RuntimeError):
     pass
 
 
+def _hash31(text: str, i: int = 0) -> int:
+    h = hashlib.sha256(text.encode()).digest()
+    return int.from_bytes(h[4 * i:4 * i + 4], "little") & 0x7FFFFFFF
+
+
 def seeds(seed: int):
     """(run seed, data seed), each under 2**31, from any whole number."""
-    h = hashlib.sha256(str(int(seed)).encode()).digest()
-    return (int.from_bytes(h[:4], "little") & 0x7FFFFFFF,
-            int.from_bytes(h[4:8], "little") & 0x7FFFFFFF)
+    return _hash31(str(int(seed))), _hash31(str(int(seed)), 1)
+
+
+# candidate run seeds a seed draws from for a window of fixed work; about
+# one in five of sync-paper's hits its work
+CANDIDATES = 256
+
+
+def equal_work_seed(cell, seed: int) -> int:
+    """The run seed of a cell that fixes its window's work: the first of
+    ``seed``'s candidate run seeds whose window trains the cell's number of
+    cohort slots (each round's cohort in whole groups), so every seed's
+    window does the same work, in another order."""
+    import numpy as np
+
+    from bench.reference import cohort_sizes
+
+    work = cell.window_work
+    cands = [_hash31(f"{int(seed)}:{j}") for j in range(CANDIDATES)]
+    sizes = cohort_sizes(cell.config, cell.traffic, cands, cell.window_steps)
+    g = work["group"]
+    hits = np.flatnonzero((g * -(-sizes // g)).sum(1) == work["slots"])
+    if not hits.size:
+        raise ValueError(f"cell {cell.name}: no candidate of seed {seed} "
+                         f"trains {work['slots']} slots in its window")
+    return cands[int(hits[0])]
 
 
 class CompileCount:
@@ -89,6 +117,57 @@ def _spans(engine):
         wrap(name)
 
 
+class HostClock:
+    """The longest call of each engine hook and of ``jax.device_get`` (the
+    loop's wait for each chunk's outputs), and the time the garbage
+    collector ran, while open: where a window's wall time went on the
+    host. Wraps from outside and restores on ``close``; changes nothing
+    that runs."""
+
+    HOOKS = ("init", "run_chunk", "evaluate", "record", "finalize")
+
+    def __init__(self, engine):
+        import jax
+
+        self.longest = {}
+        self.gc_s, self.gc_runs, self.on, self._gc_t0 = 0.0, 0, False, None
+        for name in self.HOOKS:
+            setattr(engine, name, self._timed(name, getattr(engine, name)))
+        self._device_get = jax.device_get
+        jax.device_get = self._timed("device_get", jax.device_get)
+        gc.callbacks.append(self._gc)
+
+    def _timed(self, name, fn):
+        def timed(*a, **kw):
+            t0 = time.perf_counter()
+            try:
+                return fn(*a, **kw)
+            finally:
+                if self.on:
+                    self.longest[name] = max(self.longest.get(name, 0.0),
+                                             time.perf_counter() - t0)
+
+        return timed
+
+    def _gc(self, phase, info):
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+        elif self.on and self._gc_t0 is not None:
+            self.gc_s += time.perf_counter() - self._gc_t0
+            self.gc_runs += 1
+
+    def close(self):
+        import jax
+
+        self.on = False
+        jax.device_get = self._device_get
+        gc.callbacks.remove(self._gc)
+
+    def summary(self) -> dict:
+        return {"longest_call_s": self.longest, "gc_s": self.gc_s,
+                "gc_runs": self.gc_runs}
+
+
 def _trained_clients(cfg, res) -> float:
     """Clients whose update the window aggregated."""
     if res.selection is not None:
@@ -101,16 +180,16 @@ def build(cell, seed: int):
     """The cell's data, task and engine from ``seed``, and its run seed."""
     import jax
 
-    from bench import data as data_mod
+    from bench import models
     from repro.engine import make_engine
 
     conf = cell.config
     run_seed, data_seed = seeds(seed)
+    if cell.window_work:
+        run_seed = equal_work_seed(cell, seed)
     shards = conf["run"].get("mesh_shards") or 1
-    data = data_mod.make(jax.random.PRNGKey(data_seed), conf["dataset"],
-                         conf["run"]["n_clients"],
-                         conf["dataset"]["examples_per_client"], shards)
-    model = importlib.import_module(f"bench.models.{conf['model']}")
+    model = models.load(conf)
+    data = model.make_data(jax.random.PRNGKey(data_seed), conf, shards)
     task = model.build_task(conf, data)
     cfg = cell.run_config(run_seed, cell.compare_steps)
     return data, model, task, make_engine(task, cfg), run_seed
@@ -169,21 +248,27 @@ def run(cell, seed: int, seconds: float, trace: bool,
     compare_steps = cfg.rounds
     got = compared(engine)
 
-    engine.cfg = dataclasses.replace(cfg, rounds=period)
-    t0 = time.perf_counter()
-    run_engine(engine)
-    period_s = time.perf_counter() - t0
-    periods = max(1, round(seconds / period_s))
+    period_s = None
+    if cell.window_steps:
+        periods = cell.window_steps // period
+    else:
+        engine.cfg = dataclasses.replace(cfg, rounds=period)
+        t0 = time.perf_counter()
+        run_engine(engine)
+        period_s = time.perf_counter() - t0
+        periods = max(1, round(seconds / period_s))
     if trace:
         periods = min(periods, int(cell.check["trace_max_periods"]))
     engine.cfg = dataclasses.replace(cfg, rounds=periods * period)
 
+    gc.collect()  # set-up's garbage is set-up's to collect
     log_dir = None
+    host = HostClock(engine)
     if trace:
         _spans(engine)
         log_dir = tempfile.TemporaryDirectory()
         jax.profiler.start_trace(log_dir.name)
-    counter.on = True
+    counter.on = host.on = True
     window_start = time.time()
     t0 = time.perf_counter()
     with jax.profiler.TraceAnnotation("bench.window"):
@@ -191,22 +276,26 @@ def run(cell, seed: int, seconds: float, trace: bool,
         jax.block_until_ready(res.params)
     window_s = time.perf_counter() - t0
     counter.on = False
+    host.close()
     if trace:
         jax.profiler.stop_trace()
-    mem = max(d.memory_stats().get("peak_bytes_in_use", 0)
-              if d.memory_stats() else 0 for d in used)
+    mem = peak_bytes(used)
     steps = periods * period
-    widths = conf["widths"]
-    epc, n_test = task.examples_per_client, data.test_x.shape[0]
-    eval_batch = min(500, n_test)  # fl/task's eval batch
+    epc = data.x.shape[1]  # examples per client
     flops = (_trained_clients(engine.cfg, res) * cfg.local_epochs
              * max(epc // cfg.batch_size, 1) * min(cfg.batch_size, epc)
-             * model.train_flops(widths)
-             + periods * max(n_test // eval_batch, 1) * eval_batch
-             * model.forward_flops(widths))
+             * model.train_flops(conf)
+             + periods * model.eval_examples(data)
+             * model.forward_flops(conf))
+    slots = None
+    if cell.window_work and res.selection is not None:
+        g, width = cell.window_work["group"], cfg.cohort_width()
+        slots = sum(g * -(-min(int(c), width) // g)
+                    for c in res.selection.sum(1))
     out = {
         "window": {"periods": periods, "steps": steps, "seconds": window_s,
-                   "compiles": counter.n, "period_s": period_s},
+                   "compiles": counter.n, "period_s": period_s,
+                   "slots": slots, "host": host.summary()},
         "device": {"platform": used[0].platform, "kind": used[0].device_kind,
                    "count": len(used), "memory_peak_bytes": int(mem)},
         "setup_s": window_start - T_START,
@@ -240,27 +329,51 @@ def run(cell, seed: int, seconds: float, trace: bool,
     # the reference runs once the program's state is freed
     del engine, task, res
     gc.collect()
-    ref = Reference(conf, cell.traffic, data, run_seed).follow(
+    t0 = time.perf_counter()
+    ref = Reference(model, conf, cell.traffic, data, run_seed).follow(
         compare_steps, cfg.resolved_steps_per_chunk())
+    out["reference"] = {"seconds": time.perf_counter() - t0,
+                        "process_peak_bytes": peak_bytes(used)}
     readings = oracle.compare(got, ref)
     correct, rows = oracle.judge(readings, cell.check["limits"])
-    out.update(correct=correct, rows=rows, compared={
+    out.update(correct=correct, rows=rows, readings=readings, compared={
         "eval": (list(got["eval"]), list(ref["eval"])),
         "loss": (got["loss"].tolist(), ref["loss"].tolist())})
     return out
 
 
+def peak_bytes(devices) -> int:
+    """The process's peak device memory on the fullest of ``devices``."""
+    return max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+               for d in devices)
+
+
 def emit(out: dict) -> None:
-    """Window and check lines, then the result as the last stdout line;
-    the checks are also the last lines of standard error."""
-    w = out["window"]
+    """Window, reference and check lines, then the result as the last
+    stdout line; the checks are also the last lines of standard error."""
+    w, ref = out["window"], out["reference"]
+    sized = ("fixed by the cell" if w["period_s"] is None else
+             f"one period took {w['period_s']:.4f} s in set-up")
+    if w["slots"] is not None:
+        sized += f"; {w['slots']} cohort slots trained"
     print(f"window: {w['periods']} eval periods, {w['steps']} steps in "
-          f"{w['seconds']:.4f} s (one period took {w['period_s']:.4f} s in "
-          f"set-up); compiles in window: {w['compiles']}", flush=True)
-    for what, (prog, ref) in out["compared"].items():
-        print(f"compared {what} ({len(ref)}, the first 8): program "
-              f"{prog[:8]} reference {ref[:8]}", flush=True)
+          f"{w['seconds']:.4f} s ({sized}); compiles in window: "
+          f"{w['compiles']}", flush=True)
+    host = w["host"]
+    print("window on the host: longest call " + ", ".join(
+        f"{k} {v:.4f} s" for k, v in sorted(host["longest_call_s"].items()))
+        + f"; garbage collection {host['gc_s']:.4f} s in {host['gc_runs']} "
+        "runs", flush=True)
+    print(f"reference: {ref['seconds']:.4f} s; process peak after it "
+          f"{ref['process_peak_bytes']} bytes, the window's "
+          f"{out['device']['memory_peak_bytes']}", flush=True)
+    for what, (prog, r) in out["compared"].items():
+        print(f"compared {what} ({len(r)}, the first 8): program "
+              f"{prog[:8]} reference {r[:8]}", flush=True)
     rows = out["rows"]
+    for name, value in sorted(out["readings"].items()):
+        if all(name != n for n, _, _ in rows):
+            print(f"reading {name}: {value!r} (not compared)", flush=True)
     for name, value, limit in rows:
         print(f"check {name}: {value!r} limit {limit!r}", file=sys.stderr)
     sys.stderr.flush()

@@ -26,29 +26,29 @@ import shutil
 import sys
 import traceback
 
-import run as bench_run  # bench/run.py: puts the checkout on sys.path
-
 CHUNK = "jit_chunk"
 SCHED = ("admission", "dispatch", "pop", "load_metric")
 
 
 def readings(scoped, steps: int) -> dict:
-    """Milliseconds a step under each scope group of the chunk program."""
+    """Milliseconds a step under each scope group of the chunk program
+    (``scoped`` has ``scope_s`` and ``program_s``, as ``bench.xplane.Scoped``
+    and ``bench.trace.Reduced`` do). A group none of whose scopes ran is
+    left out."""
     from bench import xplane
 
     chunk = scoped.scope_s.get(CHUNK, {})
-
-    def ms(*names):
-        return 1e3 * sum(chunk.get(n, 0.0) for n in names) / steps
-
-    out = {"sched_ms": ms(*SCHED), "pop_ms": ms("pop"),
-           "train_ms": ms("local_train"), "agg_ms": ms("aggregate"),
-           "unattributed_ms": ms(xplane.UNATTRIBUTED)}
+    groups = {"sched_ms": SCHED, "pop_ms": ("pop",),
+              "train_ms": ("local_train",), "agg_ms": ("aggregate",),
+              "unattributed_ms": (xplane.UNATTRIBUTED,)}
+    out = {name: 1e3 * sum(chunk[n] for n in scopes if n in chunk) / steps
+           for name, scopes in groups.items()
+           if any(n in chunk for n in scopes)}
     program = scoped.program_s.get(CHUNK, 0.0)
     if program:
         out["chunk_ms"] = 1e3 * program / steps
-        out["covered"] = (out["sched_ms"] + out["train_ms"]
-                          + out["agg_ms"]) / out["chunk_ms"]
+        out["covered"] = sum(out.get(n, 0.0) for n in (
+            "sched_ms", "train_ms", "agg_ms")) / out["chunk_ms"]
     return out
 
 
@@ -87,6 +87,7 @@ def main(argv=None) -> int:
     ap.add_argument("--keep", default="",
                     help="directory to copy the profiler's file into")
     args = ap.parse_args(argv)
+    import run as bench_run  # bench/run.py: puts the checkout on sys.path
     from bench import trace, xplane
     from bench.cell import load_cell
 

@@ -25,21 +25,37 @@ TINY = {
     # SGD steps a round
     "sync-paper": {"dataset": {"examples_per_client": 60, "test": 500},
                    "run": {"n_clients": 10, "k": 2, "batch_size": 5}},
+    # the cell's 4 examples a client and buffer of 10, over 512 clients;
+    # 8 steps a dispatch and an eval after each
+    "fleet-b10": {"dataset": {"test": 500},
+                  "run": {"n_clients": 512, "k": 77},
+                  "traffic": {"steps_per_chunk": 8, "eval_every": 8}},
+}
+# The bf16 control loses the updates that fall under half a bfloat16 ulp of
+# a weight, which shows at a cell's own local work: sync-paper's control is
+# cut to 3 clients that each keep 600 examples in batches of 50.
+CONTROL = {
+    "sync-paper": {"dataset": {"examples_per_client": 600, "test": 500},
+                   "run": {"n_clients": 3, "k": 1, "batch_size": 50}},
 }
 
 
-def tiny_cell(name: str):
+def tiny_cell(name: str, control: bool = False):
     from bench.cell import load_cell
 
     cell = load_cell(name)
-    cut = TINY[name]
+    cut = CONTROL.get(name, TINY[name]) if control else TINY[name]
     config = copy.deepcopy(cell.config)
     config["dataset"].update(cut["dataset"])
     config["run"].update(cut["run"])
     traffic = copy.deepcopy(cell.traffic)
     traffic["run"].update(cut.get("traffic", {}))
-    # compare one chunk of the cut traffic
+    # compare one chunk of the cut traffic; a fixed window is one period,
+    # whose work the cut fleet sets
     check = {**cell.check, "compare_steps": traffic["run"]["steps_per_chunk"]}
+    check.pop("window_work", None)
+    if "window_steps" in check:
+        check["window_steps"] = traffic["run"]["eval_every"]
     return dataclasses.replace(cell, config=config, traffic=traffic,
                                check=check)
 

@@ -7,9 +7,10 @@ import pytest
 from bench.cell import load_cell
 from bench.models import paper_cnn
 from bench.peaks import peaks
-from bench.reference import cnn_init, xent
+from bench.models.paper_cnn import cnn_forward, cnn_init, xent
 
-WIDTHS = load_cell("sync-paper").config["widths"]
+CONFIG = load_cell("sync-paper").config
+WIDTHS = CONFIG["widths"]
 
 
 def _cost_flops(fn, *args):
@@ -19,10 +20,8 @@ def _cost_flops(fn, *args):
 def test_forward_flops_match_compiler_count_at_batch_1():
     p = cnn_init(jax.random.PRNGKey(0), WIDTHS, jnp.float32)
     x = jnp.ones((1, 28, 28, 1))
-    from bench.reference import cnn_forward
-
     counted = _cost_flops(cnn_forward, p, x)
-    ours = paper_cnn.forward_flops(WIDTHS)
+    ours = paper_cnn.forward_flops(CONFIG)
     # 134 and 64 taps an axis at 28 and 14 pixels (a 5-wide SAME kernel)
     assert ours == 2 * (134**2 * 32 + 64**2 * 32 * 64 + 3136 * 512 + 5120)
     # the compiler also counts bias, ReLU and pooling: under 1% here
@@ -33,8 +32,8 @@ def test_train_flops_match_compiler_count_at_batch_1():
     p = cnn_init(jax.random.PRNGKey(0), WIDTHS, jnp.float32)
     x, y = jnp.ones((1, 28, 28, 1)), jnp.zeros((1,), jnp.int32)
     counted = _cost_flops(jax.value_and_grad(xent), p, x, y)
-    ours = paper_cnn.train_flops(WIDTHS)
-    fwd = paper_cnn.forward_flops(WIDTHS)
+    ours = paper_cnn.train_flops(CONFIG)
+    fwd = paper_cnn.forward_flops(CONFIG)
     assert ours == 2 * fwd + 2 * (64**2 * 32 * 64 + 3136 * 512 + 5120)
     # within 2% of the compiler's count, which puts the first layer's
     # weight gradient at batch 1 lower than its taps

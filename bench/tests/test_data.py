@@ -1,12 +1,14 @@
 """The on-device generator keeps the semantics of
-``repro.data.synthetic.make_image_dataset`` and makes a sharded fleet's
-data block by block, on the devices that hold it."""
+``repro.data.synthetic.make_image_dataset``, makes the fleet block by block
+as one ``lax.map`` over the blocks would, and makes a sharded fleet's data
+on the devices that hold it."""
 import os
 import subprocess
 import sys
 import textwrap
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -91,3 +93,34 @@ def test_sharded_fleet_is_made_in_place_on_four_devices():
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=300)
     assert out.returncode == 0 and out.stdout.strip().endswith("ok"), out.stderr[-2000:]
+
+
+def _stacked(key, protos, clients, per_client, size, difficulty):
+    """The blocks of ``bench.data._clients`` stacked by one ``lax.map`` in
+    one program (on a TPU the stack takes a padded layout several times
+    the fleet's size)."""
+    block = max(d for d in range(1, min(max(
+        data_mod.BLOCK_IMAGES // per_client, 1), clients) + 1)
+        if clients % d == 0)
+
+    def one(i):
+        x, y = data_mod.images(jax.random.fold_in(key, i), protos,
+                               block * per_client, size, difficulty)
+        return (x.reshape((block, per_client) + x.shape[1:]),
+                y.reshape(block, per_client))
+
+    x, y = jax.lax.map(one, jnp.arange(clients // block))
+    return x.reshape((clients,) + x.shape[2:]), y.reshape(clients, per_client)
+
+
+def test_blocks_joined_equal_the_stacked_blocks_to_the_bit(monkeypatch):
+    # 8 blocks of 512 clients at 4 examples a client
+    monkeypatch.setattr(data_mod, "BLOCK_IMAGES", 2048)
+    key = jax.random.PRNGKey(5)
+    protos = data_mod.prototypes(jax.random.fold_in(key, 0), 10, 1)
+    args = (jax.random.fold_in(key, 1), protos, 4096, 4, 28, 1.6)
+    x, y = data_mod._clients(*args)
+    sx, sy = jax.jit(_stacked, static_argnums=(2, 3, 4, 5))(*args)
+    assert x.shape == (4096, 4, 28, 28, 1) and y.shape == (4096, 4)
+    np.testing.assert_array_equal(np.asarray(x), np.asarray(sx))
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(sy))

@@ -15,7 +15,7 @@ from bench import run as bench_run
 from bench.reference import Reference
 
 SEED = 2**33 + 17  # wider than 32 bits: the harness takes any whole number
-CELLS = ["sync-paper"]
+CELLS = ["sync-paper", "fleet-b10"]
 
 
 def _run(cell):
@@ -78,9 +78,30 @@ def _answer_altered(monkeypatch):
     monkeypatch.setattr(sync, "cohort_indices", shifted)
 
 
+def _selection_altered(monkeypatch):
+    """The Markov policy selects the next client after each one it drew,
+    where the selection is made (both engines)."""
+    from repro.core import selection
+    from repro.engine import registry
+
+    markov = registry._POLICIES["markov"]
+
+    def shifted(*a, **kw):
+        policy = markov(*a, **kw)
+
+        def step(state, key):
+            sel, _ = policy.step(state, key)
+            sel = jnp.roll(sel, 1)
+            return sel, selection._advance(state, sel)
+
+        return dataclasses.replace(policy, step=step)
+
+    monkeypatch.setitem(registry._POLICIES, "markov", shifted)
+
+
 @pytest.mark.parametrize("name", CELLS)
 @pytest.mark.parametrize("fault", [_state_unchanged, _half_batch,
-                                   _answer_altered])
+                                   _answer_altered, _selection_altered])
 def test_fault_is_not_correct(tiny, monkeypatch, name, fault):
     fault(monkeypatch)
     out = _run(tiny(name))
@@ -91,13 +112,15 @@ def test_fault_is_not_correct(tiny, monkeypatch, name, fault):
 def test_control_is_not_correct(tiny, name):
     """The reference in bfloat16, in the program's place, fails a limit
     at the cell's own learning rate, epochs and batch."""
-    cell = tiny(name)
-    data, _, _, engine, run_seed = bench_run.build(cell, SEED)
+    cell = tiny(name, control=True)
+    data, model, _, engine, run_seed = bench_run.build(cell, SEED)
     steps, chunk = engine.cfg.rounds, engine.cfg.resolved_steps_per_chunk()
-    ref = Reference(cell.config, cell.traffic, data, run_seed).follow(
-        steps, chunk)
-    low = Reference(cell.config, cell.traffic, data, run_seed,
-                    jnp.bfloat16).follow(steps, chunk)
+
+    def follow(dtype):
+        return Reference(model, cell.config, cell.traffic, data, run_seed,
+                         dtype).follow(steps, chunk)
+
+    ref, low = follow(jnp.float32), follow(jnp.bfloat16)
     correct, rows = oracle.judge(oracle.compare(low, ref),
                                  cell.check["limits"])
     assert not correct, rows

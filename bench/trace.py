@@ -16,7 +16,9 @@ enqueued it). Each chip's events are shifted onto the host clock by the
 least offset that puts every program run after its enqueue, or else
 before its completion callback. Everything is then clipped to the
 ``bench.window`` span the harness puts around the timed ``run_engine``
-call, and averaged over the chips used.
+call, and averaged over the chips used. The device self time under each
+of the engine's scopes comes from ``bench/xplane.py``, on that same clock
+and window.
 """
 from __future__ import annotations
 
@@ -44,6 +46,9 @@ class Reduced:
     op_s: Dict[str, float]  # device self seconds per op, mean over chips
     collective_s: float  # device seconds in collective ops, mean over chips
     idle_gaps: List[Tuple[str, float]]  # longest gaps on the first chip
+    # device self seconds per program and engine scope, mean over chips
+    # (``bench.xplane.scope_times``)
+    scope_s: Dict[str, Dict[str, float]]
 
 
 def _union(intervals: Sequence[Tuple[float, float]]) -> List[List[float]]:
@@ -186,11 +191,14 @@ def reduce_trace(path: str, devices: int, n_gaps: int = 10) -> Reduced:
         inside = [(e2 - s2, n) for n, s2, e2 in host if s2 <= mid <= e2]
         return min(inside)[1] if inside else "run_engine loop"
 
+    from bench import xplane
+
     longest = sorted(gaps, key=lambda g: g[1] - g[0], reverse=True)[:n_gaps]
     return Reduced(
         window_s=hi - lo, busy_s=busy, devices=devices,
         program_s=dict(program_s), op_s=dict(op_s), collective_s=coll,
-        idle_gaps=[(doing(s, e), e - s) for s, e in longest])
+        idle_gaps=[(doing(s, e), e - s) for s, e in longest],
+        scope_s=xplane.scope_times(path, devices).scope_s)
 
 
 def top_ops(red: Reduced, n: int = 10) -> List[Tuple[str, float]]:
