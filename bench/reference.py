@@ -9,7 +9,7 @@ precision, the one both configurations state for the program's; here are
 only the federated semantics, whose weighted sums are float32
 (``Precision.HIGHEST``). Written for
 reading, not speed: straightforward ``jax.numpy``, one client at a time
-inside a block (``vmap`` over a block of clients, ``scan`` over blocks,
+inside a block (``vmap`` over a block of clients, a loop over blocks,
 so only one block of parameter copies is alive).
 
 The semantics it follows, per server step ``r`` (the run key is the third
@@ -42,8 +42,11 @@ import jax.numpy as jnp
 import numpy as np
 
 HIGHEST = jax.lax.Precision.HIGHEST
-# clients trained together inside one block of the cohort
+# clients trained together inside one block of the cohort, at most
 CLIENT_BLOCK = 125
+# device bytes a block may hold in parameter copies: a client in a block
+# keeps about four (its start, its trained params, a gradient, its delta)
+BLOCK_BYTES = 4 * 2**30
 I32_MAX = np.iinfo(np.int32).max
 
 
@@ -166,13 +169,22 @@ def _accumulate_gaps(acc, sel, r):
 # ---------------------------------------------------------------- runs
 
 
+def client_block(params) -> int:
+    """Clients the reference trains together: as many as keep four copies
+    of ``params`` a client within ``BLOCK_BYTES``, from 1 to
+    ``CLIENT_BLOCK``."""
+    size = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(params))
+    return max(1, min(CLIENT_BLOCK, BLOCK_BYTES // (4 * size)))
+
+
 class Reference:
     """One configuration's reference run from a seed.
 
     ``model`` is the configuration's model module (``bench.models``): its
     ``init``, ``loss`` and ``eval_loss``. ``dtype`` is the type parameters,
     data and arithmetic are held in: float32 for the reference, bfloat16
-    for its control."""
+    for its control. It trains ``client_block(params)`` clients at a
+    time: a model whose copies the chip holds few of trains one by one."""
 
     def __init__(self, model, config: dict, traffic: dict, data, seed: int,
                  dtype=jnp.float32):
@@ -197,6 +209,7 @@ class Reference:
         k_init, self.ages0, self.k_run = run_keys(seed, self.n, self.m,
                                                    self.pi)
         self.params0 = model.init(k_init, config, dtype)
+        self.client_block = client_block(self.params0)
         self.sync = run.get("mode", "sync") == "sync"
         self._chunk = jax.jit(self._sync_chunk if self.sync
                               else self._async_chunk, static_argnums=3)
@@ -204,11 +217,13 @@ class Reference:
 
     def _train(self, params_of, data, idx, keys, lrs, weights):
         """Weighted sum over the cohort of (trained params - start params)
-        and of the local losses, training ``CLIENT_BLOCK`` clients at a
+        and of the local losses, training ``client_block`` clients at a
         time. ``params_of(j)`` is slot ``j``'s start params; ``data`` holds
-        each client's examples as one row."""
+        each client's examples as one row. A loop trains the blocks up to
+        the last slot of nonzero weight and skips the rest, whose slots
+        would add nothing to either sum."""
         width = idx.shape[0]
-        blk = min(CLIENT_BLOCK, width)
+        blk = min(self.client_block, width)
         pad = -width % blk
         slots = jnp.arange(width + pad).reshape(-1, blk)
 
@@ -227,12 +242,13 @@ class Reference:
             dsum = jax.tree.map(
                 lambda s, d: s + jnp.tensordot(w.astype(d.dtype), d, axes=1,
                                                precision=HIGHEST), acc[0], deltas)
-            return (dsum, acc[1] + (w * losses.astype(jnp.float32)).sum()), None
+            return dsum, acc[1] + (w * losses.astype(jnp.float32)).sum()
 
-        zero = jax.tree.map(jnp.zeros_like, params_of(0))
-        (dsum, lsum), _ = jax.lax.scan(body, (zero, jnp.zeros((), jnp.float32)),
-                                       slots)
-        return dsum, lsum
+        zero = (jax.tree.map(jnp.zeros_like, params_of(0)),
+                jnp.zeros((), jnp.float32))
+        used = jnp.max(jnp.where(weights != 0, jnp.arange(width) + 1, 0))
+        return jax.lax.fori_loop(0, -(-used // blk),
+                                 lambda b, acc: body(acc, slots[b]), zero)
 
     def lr(self, t):
         return (jnp.asarray(self.run["lr0"], jnp.float32)

@@ -282,11 +282,14 @@ def run(cell, seed: int, seconds: float, trace: bool,
     mem = peak_bytes(used)
     steps = periods * period
     epc = data.x.shape[1]  # examples per client
-    flops = (_trained_clients(engine.cfg, res) * cfg.local_epochs
-             * max(epc // cfg.batch_size, 1) * min(cfg.batch_size, epc)
-             * model.train_flops(conf)
-             + periods * model.eval_examples(data)
-             * model.forward_flops(conf))
+    # the local SGD steps of the clients whose update the window
+    # aggregated, the examples in each, and the examples its evals ran
+    local_steps = (_trained_clients(engine.cfg, res) * cfg.local_epochs
+                   * max(epc // cfg.batch_size, 1))
+    local_batch = min(cfg.batch_size, epc)
+    eval_examples = periods * model.eval_examples(data)
+    flops = (local_steps * local_batch * model.train_flops(conf)
+             + eval_examples * model.forward_flops(conf))
     slots = None
     if cell.window_work and res.selection is not None:
         g, width = cell.window_work["group"], cfg.cohort_width()
@@ -310,7 +313,9 @@ def run(cell, seed: int, seconds: float, trace: bool,
         obs = Observed(trace=red, steps=steps, flops=flops, chips=cell.chips,
                        peak=peaks(used[0].device_kind),
                        n_clients=cfg.n_clients,
-                       buffer=cfg.buffer_size if cfg.mode == "async" else None)
+                       buffer=cfg.buffer_size if cfg.mode == "async" else None,
+                       config=conf, local_steps=local_steps,
+                       local_batch=local_batch, eval_examples=eval_examples)
         metrics = {}
         for m in cell.per_layer:
             value = read(m["name"], obs)

@@ -52,6 +52,15 @@ def readings(scoped, steps: int) -> dict:
     return out
 
 
+def under_ms(scoped, name: str, steps: int, program: str = CHUNK):
+    """Milliseconds a step of device self time under ``name`` in
+    ``program``: any ``jax.named_scope`` a model puts in its layers
+    (``scoped.under_s``, as ``bench.trace.Reduced`` has it), nested in
+    an engine scope or not. None where no op of the program ran under it."""
+    s = scoped.under_s.get(program, {}).get(name)
+    return None if s is None else 1e3 * s / steps
+
+
 def summary(scoped, steps: int, n_ops: int = 8) -> dict:
     from bench import xplane
 

@@ -63,3 +63,24 @@ def tiny_cell(name: str, control: bool = False):
 @pytest.fixture
 def tiny():
     return tiny_cell
+
+
+# McMahan et al.'s 2NN has 200 units a hidden layer
+MLP_WIDTHS = {"image_size": 28, "channels": 1, "num_classes": 10,
+              "hidden": 200}
+
+
+@pytest.fixture
+def mlp_cell(tiny, monkeypatch):
+    """``mlp_cell(name)``: the tiny cell with the two-layer MLP module
+    (``toy_mlp.py``) in the CNN's place."""
+    import toy_mlp  # bench/tests/toy_mlp.py
+
+    monkeypatch.setitem(sys.modules, "bench.models.toy_mlp", toy_mlp)
+
+    def make(name):
+        cell = tiny(name)
+        config = {**cell.config, "model": "toy_mlp", "widths": MLP_WIDTHS}
+        return dataclasses.replace(cell, config=config)
+
+    return make

@@ -2,9 +2,6 @@
 model module (``toy_mlp.py``, a two-layer MLP) runs a whole run of each
 tiny cell, and the plain reference, with no edit to a file of the
 harness."""
-import dataclasses
-import sys
-
 import jax.numpy as jnp
 import pytest
 
@@ -14,20 +11,6 @@ from bench.reference import Reference
 import toy_mlp  # bench/tests/toy_mlp.py
 
 SEED = 2**33 + 29
-# McMahan et al.'s 2NN has 200 units a hidden layer
-WIDTHS = {"image_size": 28, "channels": 1, "num_classes": 10, "hidden": 200}
-
-
-@pytest.fixture
-def mlp_cell(tiny, monkeypatch):
-    monkeypatch.setitem(sys.modules, "bench.models.toy_mlp", toy_mlp)
-
-    def make(name):
-        cell = tiny(name)
-        config = {**cell.config, "model": "toy_mlp", "widths": WIDTHS}
-        return dataclasses.replace(cell, config=config)
-
-    return make
 
 
 @pytest.mark.parametrize("name", ["sync-paper", "fleet-b10"])

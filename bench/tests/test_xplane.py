@@ -7,6 +7,8 @@ is under the scopes ``pop`` (the ``event_topk`` kernel) and
 ``local_train`` (a ``jax.grad`` under ``vmap``, the scope inside the
 differentiated function), inside the run loop's host spans.
 ``data/small.xplane.pb`` is ``test_trace.py``'s trace, with no scope."""
+import dataclasses
+import re
 from pathlib import Path
 
 import pytest
@@ -95,3 +97,63 @@ def test_idle_gaps_are_named_by_the_run_loop_spans(scoped):
     assert secs == sorted(secs, reverse=True)
     assert names[:3] == ["run_engine.record"] * 3
     assert all(s >= 0.002 for s in secs[:3])
+
+
+# ``scope_s`` of ``scoped.xplane.pb`` to the bit: the engine scopes' readers
+# (``sched_ms``, ``train_ms``, ...) read the same whatever else is kept
+PINNED = {"jit_chunk": {"unattributed": 5.2578000002412306e-08,
+                        "pop": 2.4760467999998537e-05,
+                        "local_train": 2.8147421999996647e-05}}
+
+
+def test_the_engine_scopes_read_as_before_to_the_bit(scoped):
+    assert scoped.scope_s == PINNED
+
+
+def test_the_time_under_a_component_holds_its_engine_scope(scoped):
+    under, chunk = scoped.under_s["jit_chunk"], scoped.scope_s["jit_chunk"]
+    assert set(chunk) - {xplane.UNATTRIBUTED} <= set(xplane.ENGINE_SCOPES)
+    for scope in set(chunk) - {xplane.UNATTRIBUTED}:
+        assert under[scope] >= chunk[scope] > 0
+    # every op that carries a path is under the program's own name, once
+    scoped_ops = sum(v for k, v in chunk.items() if k != xplane.UNATTRIBUTED)
+    assert scoped_ops <= under["chunk"] <= sum(chunk.values())
+
+
+@pytest.mark.parametrize("path,names", [
+    ("jit(chunk)/vmap(transpose(jvp(local_train)))/moe/mul",
+     {"chunk", "local_train", "moe", "mul"}),
+    ("jit(chunk)/while/body/local_train/while/body/dot_general",
+     {"chunk", "while", "body", "local_train", "dot_general"}),
+    ("jit(chunk)/local_train/vmap(jvp())/dot_general",
+     {"chunk", "local_train", "dot_general"}),
+    ("", set()),
+])
+def test_a_component_is_a_name_out_of_its_wrappers(path, names):
+    assert xplane.components(path) == names
+
+
+def with_moe(monkeypatch):
+    """The scoped trace's ops with a model's ``moe`` scope put inside
+    every ``local_train``: ``.../vmap(transpose(jvp(local_train)))/moe/
+    dot_general``."""
+    ops = xplane.device_ops(str(SCOPED))
+    nested = {i: [dataclasses.replace(o, path=re.sub(
+        r"(local_train\)*)/", r"\1/moe/", o.path, count=1)) for o in chip]
+        for i, chip in ops.items()}
+    monkeypatch.setattr(xplane, "device_ops", lambda path: nested)
+    return nested
+
+
+def test_a_model_scope_in_an_engine_scope_counts_under_both(scoped,
+                                                            monkeypatch):
+    nested = with_moe(monkeypatch)
+    assert any(o.path.endswith("(local_train)))/moe/dot_general")
+               for o in nested[0])
+    got = xplane.scope_times(str(SCOPED), devices=1)
+    # the engine scopes keep every op: moe is none of them
+    assert got.scope_s == scoped.scope_s
+    under = got.under_s["jit_chunk"]
+    assert under["moe"] == under["local_train"] == (
+        scoped.scope_s["jit_chunk"]["local_train"])
+    assert "moe" not in scoped.under_s["jit_chunk"]

@@ -17,8 +17,8 @@ least offset that puts every program run after its enqueue, or else
 before its completion callback. Everything is then clipped to the
 ``bench.window`` span the harness puts around the timed ``run_engine``
 call, and averaged over the chips used. The device self time under each
-of the engine's scopes comes from ``bench/xplane.py``, on that same clock
-and window.
+of the engine's scopes, and under any name on the ops' name paths, comes
+from ``bench/xplane.py``, on that same clock and window.
 """
 from __future__ import annotations
 
@@ -49,6 +49,9 @@ class Reduced:
     # device self seconds per program and engine scope, mean over chips
     # (``bench.xplane.scope_times``)
     scope_s: Dict[str, Dict[str, float]]
+    # device self seconds per program under each name on the ops' name
+    # paths, any ``jax.named_scope`` among them (``bench.xplane.Scoped``)
+    under_s: Dict[str, Dict[str, float]]
 
 
 def _union(intervals: Sequence[Tuple[float, float]]) -> List[List[float]]:
@@ -194,11 +197,12 @@ def reduce_trace(path: str, devices: int, n_gaps: int = 10) -> Reduced:
     from bench import xplane
 
     longest = sorted(gaps, key=lambda g: g[1] - g[0], reverse=True)[:n_gaps]
+    scoped = xplane.scope_times(path, devices)
     return Reduced(
         window_s=hi - lo, busy_s=busy, devices=devices,
         program_s=dict(program_s), op_s=dict(op_s), collective_s=coll,
         idle_gaps=[(doing(s, e), e - s) for s, e in longest],
-        scope_s=xplane.scope_times(path, devices).scope_s)
+        scope_s=scoped.scope_s, under_s=scoped.under_s)
 
 
 def top_ops(red: Reduced, n: int = 10) -> List[Tuple[str, float]]:

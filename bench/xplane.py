@@ -11,7 +11,8 @@ wire format; the message layout is ``tsl/profiler/protobuf/xplane.proto``),
 and each event is joined to its metadata by the metadata id.
 
 ``scope_times`` then gives the device self seconds under each of a set of
-scopes, on the same clock and window as ``bench.trace.reduce_trace``.
+scopes, and under any name on the ops' paths, on the same clock and window
+as ``bench.trace.reduce_trace``.
 """
 from __future__ import annotations
 
@@ -202,6 +203,19 @@ def scope_of(path: str, scopes: Sequence[str]) -> str:
     return found
 
 
+def components(path: str) -> frozenset:
+    """The names a name path is made of, each taken out of its
+    transformation wrappers (``vmap(transpose(jvp(moe)))`` -> ``moe``): the
+    ``jax.named_scope``s the op ran under, among the program's, loops' and
+    primitives' own names."""
+    out = set()
+    for part in path.split("/"):
+        m = re.fullmatch(r"(?:[\w.-]+\()*([^()]*?)\)*", part)
+        out.add(m.group(1) if m else part)
+    out.discard("")
+    return frozenset(out)
+
+
 @dataclasses.dataclass
 class Scoped:
     # device self seconds per program (``jit_chunk``) and scope, mean over
@@ -211,6 +225,11 @@ class Scoped:
     paths: Dict[str, str]  # op -> its path (the first one seen)
     program_s: Dict[str, float]  # device seconds per program, mean over chips
     idle_gaps: List[Tuple[str, float]]  # gaps named by their host span
+    # device self seconds per program under each component of the ops'
+    # name paths (``components``), mean over chips: an op counts once
+    # under each, so a model's own scope nested inside an engine scope
+    # reads its time without taking it from ``scope_s``
+    under_s: Dict[str, Dict[str, float]]
 
 
 def _host(path: str, span_names: Iterable[str]):
@@ -249,7 +268,8 @@ def _host(path: str, span_names: Iterable[str]):
 
 def scope_times(path: str, devices: int, n_gaps: int = 10) -> Scoped:
     """Device self seconds under each of ``ENGINE_SCOPES`` (and
-    ``UNATTRIBUTED``), per program, in the ``bench.window`` span of the
+    ``UNATTRIBUTED``), and under every component of the ops' name paths,
+    per program, in the ``bench.window`` span of the
     trace at ``path``, over the first ``devices`` chips; the ``n_gaps``
     longest idle gaps on the first chip, each named by the one of
     ``RUN_ENGINE_SPANS`` that is the innermost for the longest part of it
@@ -261,9 +281,11 @@ def scope_times(path: str, devices: int, n_gaps: int = 10) -> Scoped:
         raise ValueError(f"trace holds {len(chips)} device planes with ops, "
                          f"expected {devices}")
     scope_s: Dict[str, Dict[str, float]] = defaultdict(lambda: defaultdict(float))
+    under_s: Dict[str, Dict[str, float]] = defaultdict(lambda: defaultdict(float))
     op_s: Dict[Tuple[str, str, str], float] = defaultdict(float)
     program_s: Dict[str, float] = defaultdict(float)
     paths: Dict[str, str] = {}
+    names: Dict[str, frozenset] = {}  # path -> its components
     gaps: List[Tuple[float, float]] = []
     for i in chips:
         mod = modules.get(i)
@@ -289,6 +311,10 @@ def scope_times(path: str, devices: int, n_gaps: int = 10) -> Scoped:
             scope = scope_of(op.path, ENGINE_SCOPES)
             scope_s[program][scope] += secs / devices
             op_s[(program, scope, op.name)] += secs / devices
+            if op.path not in names:
+                names[op.path] = components(op.path)
+            for name in names[op.path]:
+                under_s[program][name] += secs / devices
         if i == chips[0]:
             merged = trace._union([o[1:] for o in ops])
             edges = [lo] + [x for iv in merged for x in iv] + [hi]
@@ -309,4 +335,5 @@ def scope_times(path: str, devices: int, n_gaps: int = 10) -> Scoped:
     longest = sorted(gaps, key=lambda g: g[1] - g[0], reverse=True)[:n_gaps]
     return Scoped(scope_s={p: dict(v) for p, v in scope_s.items()},
                   op_s=dict(op_s), paths=paths, program_s=dict(program_s),
-                  idle_gaps=[(doing(s, e), e - s) for s, e in longest])
+                  idle_gaps=[(doing(s, e), e - s) for s, e in longest],
+                  under_s={p: dict(v) for p, v in under_s.items()})
