@@ -26,6 +26,7 @@ windows actually show up.
 """
 from __future__ import annotations
 
+import math
 from typing import Dict, Optional
 
 import jax
@@ -77,6 +78,36 @@ def _init_stats(heartbeat: bool = False, redispatch: bool = False,
     return out
 
 
+def client_rows(data):
+    """``data`` with each leaf of more than two axes held as rows,
+    ``(n_clients, prod(rest))``; leaves of one or two axes as they are.
+
+    A leaf whose per-client axes end in a small one (28x28x1 images) gets a
+    client-minor device layout, and a per-step gather of a few clients from
+    it then relays out the whole fleet's examples inside the step loop.
+    Rows keep the client axis major, so the gather reads whole rows. Shapes
+    (``jax.ShapeDtypeStruct``) map to shapes, for compiling without data.
+    """
+    def rows(a):
+        if a.ndim <= 2:
+            return a
+        shape = (a.shape[0], math.prod(a.shape[1:]))
+        if isinstance(a, jax.ShapeDtypeStruct):
+            return jax.ShapeDtypeStruct(shape, a.dtype,
+                                        sharding=a.sharding)
+        return jnp.reshape(a, shape)
+
+    return jax.tree.map(rows, data)
+
+
+def gather_rows(rows, idx, like):
+    """The clients ``idx`` of ``rows`` (``client_rows(like)``, or ``like``'s
+    own layout) in ``like``'s per-client shapes: ``a[idx]`` for each leaf
+    ``a`` of ``like``, value for value."""
+    return jax.tree.map(
+        lambda r, a: r[idx].reshape(idx.shape + a.shape[1:]), rows, like)
+
+
 class AsyncEngine:
     """Asynchronous server steps: one buffer flush per step, clients train
     from (possibly stale) ring-buffered model versions."""
@@ -111,7 +142,7 @@ class AsyncEngine:
         self._init_state, core = self._build_step()
         self._chunk = ChunkRunner(
             core, aux_keys=("loss", "clock", "version", "buffer_fill"),
-            data=self.task.client_data,
+            data=client_rows(self.task.client_data),
         )
 
     def _build_step(self):
@@ -257,7 +288,9 @@ def _make_async_step(
     """Builds ``(init_state, step core)`` with ``step(state, key, data) ->
     (state, aux)`` — the pure function the chunked scan body folds over
     (``ChunkRunner`` also drives single steps through a length-1 chunk);
-    ``data`` is ``task.client_data``, passed in rather than closed over.
+    ``data`` is ``client_rows(task.client_data)``, passed in rather than
+    closed over; the cohort's rows take ``task.client_data``'s per-client
+    shapes back before local training.
 
     The optional hooks are the mesh-sharding seam (``repro.engine.sharded``
     supplies them; the single-device engine runs with identity defaults):
@@ -548,7 +581,7 @@ def _make_async_step(
             disp_params = cohort_layout(
                 jax.tree.map(lambda h: h[read_ver % H], state["hist"])
             )
-            shards = cohort_layout(jax.tree.map(lambda a: a[idx], data))
+            shards = cohort_layout(gather_rows(data, idx, task.client_data))
             keys = jax.random.split(k_local, B)
             if cohort_pad:
                 # the first B keys must stay the exact draws of the unpadded

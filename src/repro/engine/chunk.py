@@ -50,7 +50,11 @@ class ChunkRunner:
     data). It enters each compiled chunk as an argument, never donated:
     closed over, XLA would embed it as a constant, and at fleet scale
     (gigabytes of client examples) that costs minutes of compile and a
-    program too large for the persistent compilation cache.
+    program too large for the persistent compilation cache. The async
+    engine passes it as rows, ``async_engine.client_rows``: a leaf with a
+    small minor axis (28x28x1 images) gets a client-minor layout, and the
+    per-step gather of a few clients would relay out the whole fleet's
+    examples inside the scan.
     """
 
     def __init__(self, step_fn: Callable, aux_keys: Tuple[str, ...],

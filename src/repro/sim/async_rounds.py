@@ -49,13 +49,13 @@ def make_async_step(
     task: FLTask, fl: FLConfig, acfg: AsyncConfig, policy: Policy
 ):
     """Builds (init_state, jitted step) for one async server step (legacy
-    helper); ``step(state, key)`` reads ``task.client_data`` as an
-    argument of the compiled step."""
+    helper); ``step(state, key)`` reads ``task.client_data``, as rows
+    (``client_rows``), as an argument of the compiled step."""
     import functools
 
     import jax
 
-    from repro.engine.async_engine import _make_async_step
+    from repro.engine.async_engine import _make_async_step, client_rows
     from repro.engine.config import run_config_from_legacy
     from repro.engine.registry import make_aggregator
 
@@ -67,7 +67,8 @@ def make_async_step(
     init_state, step = _make_async_step(
         task, cfg, policy, agg, acfg.resolved_profile()
     )
-    return init_state, functools.partial(jax.jit(step), data=task.client_data)
+    return init_state, functools.partial(jax.jit(step),
+                                         data=client_rows(task.client_data))
 
 
 def run_async_training(

@@ -21,6 +21,7 @@ from repro.configs.paper_cnn import MNIST_CNN
 from repro.core.selection import Policy
 from repro.data.synthetic import make_image_dataset
 from repro.engine import AsyncEngine, RunConfig, SyncEngine, run_engine
+from repro.engine.async_engine import client_rows
 from repro.engine.config import chunk_plan
 
 SMALL_CNN = dataclasses.replace(
@@ -151,15 +152,19 @@ def test_empty_cohort_reports_nan_loss(small_task, mode):
 def test_client_data_is_a_chunk_argument(small_task, mode):
     """Client data reaches the compiled chunk as an argument. Closed
     over, it would be an XLA constant of every chunk program: gigabytes
-    at fleet scale, minutes of compile, too large for the compile cache."""
+    at fleet scale, minutes of compile, too large for the compile cache.
+    The async engine passes it as rows (``client_rows``)."""
     kw = dict(profile="lognormal", buffer_size=3) if mode == "async" else {}
     make = SyncEngine if mode == "sync" else AsyncEngine
     engine = make(small_task, _cfg(mode=mode, **kw))
     lowered = engine._chunk.lower(engine.init(), 0, 2, False)
     data_args = jax.tree.leaves(lowered.args_info[0][1])
-    data = jax.tree.leaves(small_task.client_data)
+    given = (client_rows(small_task.client_data) if mode == "async"
+             else small_task.client_data)
+    data = jax.tree.leaves(given)
     assert [a.shape for a in data_args] == [d.shape for d in data]
-    x = small_task.client_data["x"]
-    x_type = "tensor<" + "x".join(map(str, x.shape)) + "xf32>"
+    x_types = ["tensor<" + "x".join(map(str, x.shape)) + "xf32>"
+               for x in (given["x"], small_task.client_data["x"])]
     assert not [line for line in lowered.as_text().splitlines()
-                if "stablehlo.constant" in line and x_type in line]
+                if "stablehlo.constant" in line
+                and any(t in line for t in x_types)]

@@ -9,6 +9,7 @@ same tests and only the one that runs this file loads the TPU library.
 """
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -70,3 +71,66 @@ def test_fedavg_reduce_compiles_at_paper_cnn_width(one_chip):
     weights = jax.ShapeDtypeStruct((cohort,), jnp.float32, sharding=one_chip)
     text = _compiled_text(fedavg_reduce.fedavg_reduce, params, weights)
     assert "tpu_custom_call" in text
+
+
+def _while_bodies(text):
+    """The HLO text of every ``while`` loop body in a compiled module."""
+    bodies = set(re.findall(r"\bbody=%?([\w.\-]+)", text))
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    return {b: "\n".join(comps.get(b, ())) for b in bodies}
+
+
+def test_async_chunk_gathers_client_rows_inside_the_loop(one_chip):
+    """The async chunk's per-step cohort gather reads client rows where
+    they lie: no copy of the whole fleet's examples runs inside the scan,
+    and the chunk's temporaries stay below the examples' own bytes (the
+    padded relayout of 28x28 images is what they would otherwise cost).
+    The fleet is large enough that the images' bytes outweigh the
+    temporaries every fleet size pays (the cohort's local training)."""
+    import dataclasses
+
+    import numpy as np
+
+    from repro.data.synthetic import ImageDataset
+    from repro.engine import RunConfig, make_engine
+    from repro.fl.task import make_cnn_task
+
+    n, epc, side = 65536, 4, 28
+    cnn = CNN_CONFIGS["paper-cnn-mnist"]
+    one = ImageDataset(cnn.name, np.zeros((1, side, side, 1), np.float32),
+                       np.zeros((1,), np.int32))
+    task = make_cnn_task(cnn, one, one, n_clients=1)
+    x = jax.ShapeDtypeStruct((n, epc, side, side, 1), jnp.float32,
+                             sharding=one_chip)
+    y = jax.ShapeDtypeStruct((n, epc), jnp.int32, sharding=one_chip)
+    task = dataclasses.replace(task, client_data={"x": x, "y": y},
+                               examples_per_client=epc)
+    cfg = RunConfig(mode="async", n_clients=n, k=n * 15 // 100, m=10,
+                    aggregator="fedbuff", buffer_size=10, local_epochs=5,
+                    batch_size=50, steps_per_chunk=8, eval_every=8,
+                    collect_history=False, use_kernel=True, rounds=8)
+    engine = make_engine(task, cfg)
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        engine.init())
+    r0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    runner = engine._chunk
+    compiled = runner._fn(8, False).lower(state, runner._data, r0).compile()
+
+    fleet = n * epc * side * side
+    bodies = _while_bodies(compiled.as_text())
+    assert bodies
+    for name, body in bodies.items():
+        for m in re.finditer(r"= \w+\[([\d,]*)\][^\n]*? copy(?:-start)?\(",
+                             body):
+            elems = math.prod(int(d) for d in m.group(1).split(",") if d)
+            assert elems < fleet, f"{name} copies {elems} elements: {m[0]}"
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < fleet * 4, f"temporaries {temp} B"
