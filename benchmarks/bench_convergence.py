@@ -15,9 +15,8 @@ import numpy as np
 from repro.configs.paper_cnn import CNN_CONFIGS
 from repro.core import load_metric as lm
 from repro.data.synthetic import load_dataset
-from repro.engine import RunConfig, SyncEngine, run_engine
+from repro.engine import RunConfig, SyncEngine, rounds_to_target, run_engine
 from repro.fl import make_cnn_task
-from repro.fl.rounds import rounds_to_target
 
 # (dataset, noniid, target_acc, paper figure, cpu-budget scale multiplier)
 EXPERIMENTS = [

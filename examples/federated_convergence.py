@@ -14,7 +14,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmarks.bench_convergence import run_one
 from repro.core import load_metric as lm
-from repro.fl.rounds import rounds_to_target
+from repro.engine import rounds_to_target
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--paper-scale", action="store_true")

@@ -16,7 +16,8 @@ from repro.core import (
     simulate,
 )
 from repro.data.synthetic import load_dataset
-from repro.fl import FLConfig, make_cnn_task, run_training
+from repro.engine import RunConfig, make_engine, run_engine
+from repro.fl import make_cnn_task
 
 N, K, M = 100, 15, 10  # the paper's simulation setting
 
@@ -38,8 +39,8 @@ for name in ("random", "markov"):
 train, test = load_dataset("mnist", scale=0.1)
 task = make_cnn_task(MNIST_CNN, train, test, N)
 for policy in ("random", "markov"):
-    fl = FLConfig(n_clients=N, k=K, m=M, policy=policy, rounds=8,
-                  local_epochs=2, batch_size=12, eval_every=4)
-    out = run_training(task, fl, progress=True)
-    print(f"{policy}: final acc {out['history']['accuracy'][-1]:.3f}, "
-          f"Var[X]={out['load_stats']['var_X']:.3f}")
+    cfg = RunConfig(n_clients=N, k=K, m=M, policy=policy, rounds=8,
+                    local_epochs=2, batch_size=12, eval_every=4)
+    res = run_engine(make_engine(task, cfg), progress=True)
+    print(f"{policy}: final acc {res.records[-1].accuracy:.3f}, "
+          f"Var[X]={res.load_stats['var_X']:.3f}")

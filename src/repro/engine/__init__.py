@@ -9,14 +9,13 @@ One contract for every run, sync or async:
   * **Protocols** — ``Policy`` (explicit state pytree, ``init/step``),
     ``Aggregator`` (pure ``weigh/init/accumulate/finalize``), ``Engine``
     (``init/step/finalize``).
-  * **Contract** — ``RunConfig`` in (absorbing the legacy
-    ``FLConfig``/``AsyncConfig`` pair), ``RunResult``/``RoundRecord`` out,
+  * **Contract** — ``RunConfig`` in, ``RunResult``/``RoundRecord`` out,
     with one JSON-safe serializer (``to_jsonable``/``dump_json``).
 
 The paper's policies live in ``repro.core.selection`` and register
 themselves on import; ``fedavg``/``fedbuff``/``fedprox`` aggregators in
-``repro.engine.aggregators``. ``repro.fl.run_training`` and
-``repro.sim.run_async_training`` remain as thin back-compat wrappers.
+``repro.engine.aggregators``. ``make_engine`` / ``run_engine`` are the
+one way to build and start a run.
 """
 from repro.engine.registry import (  # noqa: F401
     aggregator_names,
@@ -33,7 +32,7 @@ from repro.engine.config import (  # noqa: F401
     RoundRecord,
     RunConfig,
     RunResult,
-    run_config_from_legacy,
+    rounds_to_target,
 )
 from repro.engine.api import (  # noqa: F401
     HISTORY_CELL_CAP,

@@ -4,16 +4,16 @@ One jit'd server step = admission control (idle+available clients consult
 their selection policy — the Markov chain decides *locally* whether to
 pull the model, preserving the paper's zero-coordination property) ->
 dispatch with sampled wall-clock latencies -> pop the next ``buffer_size``
-completions (event_topk kernel at fleet scale) -> vmapped local training
-from each client's *dispatch-time* model version (a ring buffer of the
-last ``max_versions`` global models) -> aggregator
-``weigh/init/accumulate/finalize`` over the buffered deltas -> clock/
+completions (event_topk kernel at fleet scale) -> the cohort stage
+(``repro.engine.cohort``: vmapped local training from each client's
+*dispatch-time* model version, a ring buffer of the last
+``max_versions`` global models, then the aggregator
+``weigh/init/accumulate/finalize`` over the buffered deltas) -> clock/
 version advance.
 
-This is ``sim/async_rounds.py`` re-expressed against the ``Engine``
-protocol with the aggregation seam opened up: the default ``fedbuff``
-aggregator reproduces the pre-refactor staleness-discounted delta mean
-bit-for-bit (pinned by ``tests/test_engine_equivalence.py``). With the
+The default ``fedbuff`` aggregator reproduces the pre-refactor
+staleness-discounted delta mean of the buffered loop bit-for-bit
+(pinned by ``tests/test_engine_equivalence.py``). With the
 degenerate ``uniform`` latency profile (zero spread, always available, no
 dropout) and ``buffer_size = k`` every dispatch completes inside its own
 step with staleness 0, and the loop reproduces the synchronous FedAvg
@@ -26,7 +26,6 @@ windows actually show up.
 """
 from __future__ import annotations
 
-import math
 from typing import Dict, Optional
 
 import jax
@@ -34,21 +33,27 @@ import jax.numpy as jnp
 
 from repro.core.aoi import age_update, peak_age_accumulate
 from repro.core.load_metric import (
-    empirical_load_stats,
     init_selection_accum,
-    selection_stats_from_accum,
-    tier_stats_from_accum,
+    init_tier_accum,
+    update_tier_accum,
 )
 from repro.core.selection import Policy
-from repro.engine.aggregators import Aggregator, acc_stats
+from repro.engine.aggregators import Aggregator
 from repro.engine.chunk import ChunkRunner, dealias_pytree, run_key, step_once
+from repro.engine.cohort import (
+    CohortStage,
+    client_rows,
+    make_aggregate,
+    run_result,
+)
 from repro.engine.config import RoundRecord, RunConfig, RunResult
 from repro.engine.registry import make_aggregator, make_policy
-from repro.fl.client import make_local_update
 from repro.fl.task import FLTask
 from repro.optim.schedules import exponential_decay
 from repro.sim import events as ev_mod
 from repro.sim import latency as lat_mod
+from repro.topo import heartbeat as hb_mod
+from repro.topo.reduce import make_hop_latency
 
 
 def _resolved_profile(profile) -> lat_mod.LatencyProfile:
@@ -76,36 +81,6 @@ def _init_stats(heartbeat: bool = False, redispatch: bool = False,
     for s in agg_stats:
         out[f"agg_{s}"] = z  # aggregator telemetry (e.g. norm_clip)
     return out
-
-
-def client_rows(data):
-    """``data`` with each leaf of more than two axes held as rows,
-    ``(n_clients, prod(rest))``; leaves of one or two axes as they are.
-
-    A leaf whose per-client axes end in a small one (28x28x1 images) gets a
-    client-minor device layout, and a per-step gather of a few clients from
-    it then relays out the whole fleet's examples inside the step loop.
-    Rows keep the client axis major, so the gather reads whole rows. Shapes
-    (``jax.ShapeDtypeStruct``) map to shapes, for compiling without data.
-    """
-    def rows(a):
-        if a.ndim <= 2:
-            return a
-        shape = (a.shape[0], math.prod(a.shape[1:]))
-        if isinstance(a, jax.ShapeDtypeStruct):
-            return jax.ShapeDtypeStruct(shape, a.dtype,
-                                        sharding=a.sharding)
-        return jnp.reshape(a, shape)
-
-    return jax.tree.map(rows, data)
-
-
-def gather_rows(rows, idx, like):
-    """The clients ``idx`` of ``rows`` (``client_rows(like)``, or ``like``'s
-    own layout) in ``like``'s per-client shapes: ``a[idx]`` for each leaf
-    ``a`` of ``like``, value for value."""
-    return jax.tree.map(
-        lambda r, a: r[idx].reshape(idx.shape + a.shape[1:]), rows, like)
 
 
 class AsyncEngine:
@@ -237,45 +212,13 @@ class AsyncEngine:
         }
         if "hb_expired" in st:
             wall_stats["hb_expired"] = int(st["hb_expired"])
-        if sel_hist is not None:
-            load_stats = empirical_load_stats(sel_hist)
-        else:
-            load_stats = selection_stats_from_accum(state["load_acc"])
-        load_stats = dict(load_stats)
-        if "tier_acc" in state:
-            load_stats.update(tier_stats_from_accum(state["tier_acc"]))
-        if "faults" in state:
-            for nm, cnt in self.fault_set.counters(state["faults"]).items():
-                load_stats[f"fault_{nm}_injected"] = cnt
+        stats = {f"agg_{s}": float(st[f"agg_{s}"])
+                 for s in self.aggregator.stat_names}
         if "redispatched" in st:
-            load_stats["redispatched"] = int(st["redispatched"])
-            load_stats["rd_expired"] = int(st["rd_expired"])
-        for s in self.aggregator.stat_names:
-            load_stats[f"agg_{s}"] = float(st[f"agg_{s}"])
-        if "defense" in state:
-            load_stats.update(self.defense.report(state["defense"]))
-            if "tier_acc" in state:
-                from repro.topo.reduce import tier_suspect_counts
-
-                load_stats["tier_suspects"] = tier_suspect_counts(
-                    self.topo, self.cfg.n_clients,
-                    state["defense"]["status"],
-                )
-        fault_exposure = None
-        if "faults" in state and self.cfg.fault_exposure:
-            fault_exposure = self.fault_set.exposure(state["faults"])
-        return RunResult(
-            config=self.cfg,
-            records=records,
-            selection=sel_hist,
-            load_stats=load_stats,
-            wall_stats=wall_stats,
-            params=state["params"],
-            wall_time_s=wall_time_s,
-            fault_exposure=fault_exposure,
-            defense=(self.defense.arrays(state["defense"])
-                     if "defense" in state else None),
-        )
+            stats["redispatched"] = int(st["redispatched"])
+            stats["rd_expired"] = int(st["rd_expired"])
+        return run_result(self, state, records, sel_hist, wall_time_s, stats,
+                          wall_stats=wall_stats)
 
 
 def _make_async_step(
@@ -289,8 +232,9 @@ def _make_async_step(
     (state, aux)`` — the pure function the chunked scan body folds over
     (``ChunkRunner`` also drives single steps through a length-1 chunk);
     ``data`` is ``client_rows(task.client_data)``, passed in rather than
-    closed over; the cohort's rows take ``task.client_data``'s per-client
-    shapes back before local training.
+    closed over. From local training to the new params the step runs the
+    cohort stage (``repro.engine.cohort.CohortStage``), one vmap over the
+    buffer's slots, each from its dispatch-time version.
 
     The optional hooks are the mesh-sharding seam (``repro.engine.sharded``
     supplies them; the single-device engine runs with identity defaults):
@@ -303,12 +247,9 @@ def _make_async_step(
         cohort-parallel mode (``RunConfig.shard_cohort``) lays them out
         ``P(fleet)`` instead so each device trains only its slice of the
         cohort;
-      * ``aggregate(params, updates, bases, w, idx) -> params`` replaces
-        the inline ``init/accumulate/finalize`` chain (the cohort-parallel
-        mode routes it through ``aggregators.cohort_sharded_apply``:
-        shard-local accumulation merged by one psum; ``idx`` is the
-        cohort -> client map, which topology-aware reductions use to
-        route each slot to its tier-0 node);
+      * ``aggregate`` is the cohort stage's aggregate hook
+        (``cohort.make_aggregate``; the cohort-parallel mode passes the
+        one that accumulates shard-locally and merges by one psum);
       * ``cohort_pad`` appends that many zero-weight slots to the popped
         cohort so the padded axis divides the mesh (invalid slots, masked
         everywhere exactly like an under-filled buffer);
@@ -316,7 +257,7 @@ def _make_async_step(
         carry so the donated scan aliases buffers instead of resharding.
 
     ``topo`` (a ``repro.topo.Topology``) reshapes the aggregation: the
-    default aggregate becomes the tiered reduction, every dispatch pays
+    default aggregate is the tiered reduction, every dispatch pays
     the per-hop DAG latency under a dedicated key fold, the per-tier
     load accumulators ride the state, and a non-zero
     ``heartbeat_timeout`` excludes dark clients from their tier's
@@ -336,11 +277,9 @@ def _make_async_step(
     ``defense`` (a ``repro.defense.Defense``) closes the detect ->
     quarantine -> adapt loop inside this same step under the same rule:
     armed, it adds its ``(n,)`` reputation/status state to the carry,
-    draws its probation/readmit coins under dedicated fold 108, vetoes
-    quarantined clients at the selection seam (``send &= ~blocked``) and
-    suspect updates at the aggregation seam (``succ &= ~suspect`` — the
-    exact seam heartbeat dark-clients use), and, with mtd configured,
-    swaps the aggregate hook for the moving-target wrapper. Disarmed:
+    vetoes quarantined clients at the selection seam (``send &=
+    ~blocked``), and the cohort stage scores the arrived updates and
+    excludes suspects from the reduction (fold 108). Disarmed:
     no state key, no fold, no op (``tests/test_defense.py`` pins the
     structural golden and the armed-but-never-triggered bitwise one).
     """
@@ -353,29 +292,9 @@ def _make_async_step(
     have_faults = faults is not None
     have_def = defense is not None
     rd_on = (cfg.redispatch_timeout or 0) > 0
-    kill_on = have_faults and faults.has("kill")
-    if have_faults and (faults.has("scale") or faults.has("noise")):
-        from repro.faults.inject import corrupt_updates
-    collude_on = have_faults and faults.has("collude")
-    if collude_on:
-        from repro.faults.inject import collude_updates
-    col_on = have_def and defense.collusion
-    # supervised labels for the learned detector head: only when the run
-    # opted into exposure ground truth AND some fault actually pops
-    sup_on = (have_def and defense.wants_labels and have_faults
-              and faults.has_pop and cfg.fault_exposure)
-    if sup_on:
-        from repro.faults.inject import effects_hit
     if tiered:
-        from repro.core.load_metric import init_tier_accum, update_tier_accum
-        from repro.topo.reduce import make_hop_latency, tiered_apply
-
         assign_dev = jnp.asarray(topo.assign(n))
         hop_fn = make_hop_latency(topo, n)
-    if hb_timeout > 0 or rd_on:
-        # re-dispatch deadlines reuse the heartbeat liveness predicate:
-        # "no completion for longer than the timeout" is the same signal
-        from repro.topo import heartbeat as hb_mod
     if pop is None:
         def pop(ev):
             return ev_mod.pop_events(ev, B, use_kernel=cfg.use_kernel)
@@ -383,24 +302,10 @@ def _make_async_step(
         cohort_layout = lambda tree: tree  # noqa: E731
     if constrain_state is None:
         constrain_state = lambda state: state  # noqa: E731
-    if aggregate is None:
-        if tiered:
-            aggregate = tiered_apply(agg, topo, n)
-        else:
-            def aggregate(g, updates, bases, w, idx=None):
-                acc = agg.accumulate(agg.init(g), updates, bases, w)
-                return agg.finalize(g, acc), acc_stats(acc)
-    mtd_on = have_def and defense.mtd
-    if mtd_on:
-        # config rejects mtd under tiered/cohort-sharded aggregation, so
-        # the wrapped hook is always the inline (or bit-exact sharded)
-        # default; level 0 routes through it untouched via lax.cond
-        from repro.defense.adaptive import adaptive_aggregate
-
-        aggregate_mtd = adaptive_aggregate(aggregate, defense.cfg.mtd_trims,
-                                           families=defense.cfg.mtd_families)
-    local_update = make_local_update(
-        task.loss_fn, cfg.local_epochs, cfg.batch_size, task.examples_per_client
+    stage = CohortStage(
+        task, cfg, agg, Bp,
+        aggregate=aggregate or make_aggregate(agg, topo, n),
+        layout=cohort_layout, faults=faults, defense=defense,
     )
     lr_fn = exponential_decay(cfg.lr0, cfg.lr_decay)
 
@@ -438,6 +343,7 @@ def _make_async_step(
     def step(state, key, data):
         ev, sched, stats = state["ev"], state["sched"], state["stats"]
         clock, version = state["clock"], state["version"]
+        fstate = state.get("faults")
         # same key split as the sync round so the degenerate case is
         # bit-for-bit comparable; latency/dropout/gap keys are fresh folds
         k_sel, k_local = jax.random.split(key)
@@ -455,8 +361,7 @@ def _make_async_step(
                 # quarantined clients are vetoed at the admission seam (they
                 # still age); probation clients stay selectable so they keep
                 # generating evidence for re-admission
-                dstate = state["defense"]
-                send = send & ~defense.blocked(dstate)
+                send = send & ~defense.blocked(state["defense"])
             # only actual dispatches reset the AoI clock; everyone else ages
             sched = {**sched, "ages": age_update(prev_ages, send)}
             ep_sx, ep_sx2, ep_cnt = peak_age_accumulate(
@@ -475,16 +380,15 @@ def _make_async_step(
                 # fold 104: per-hop DAG latency. Only drawn when a multi-tier
                 # topology is armed, so the star key schedule is untouched
                 latency = latency + hop_fn(jax.random.fold_in(k_sel, 104))
-            if have_faults:
-                fstate = state["faults"]
+            if have_faults and faults.has_dispatch:
                 # fold 105: the fault set's dedicated key (sub-folds:
                 # 0 dispatch, 1 pop, 2 corruption noise) — armed only when
                 # faults are, so the fault-free key schedule is untouched
-                k_fault = jax.random.fold_in(k_sel, 105)
-                if faults.has_dispatch:
-                    fstate, latency = faults.on_dispatch(
-                        fstate, jax.random.fold_in(k_fault, 0), send, latency
-                    )
+                fstate, latency = faults.on_dispatch(
+                    fstate,
+                    jax.random.fold_in(jax.random.fold_in(k_sel, 105), 0),
+                    send, latency,
+                )
             if hb_timeout > 0:
                 # dispatch is a heartbeat: the client pulled the model at
                 # the current clock
@@ -548,12 +452,8 @@ def _make_async_step(
                 valid = jnp.concatenate(
                     [valid, jnp.zeros((cohort_pad,), valid.dtype)]
                 )
-            if have_faults and faults.has_pop:
-                # fold 105/1: per-slot injection coins over the popped cohort
-                fstate, eff = faults.on_pop(
-                    fstate, jax.random.fold_in(k_fault, 1), idx, valid
-                )
-                eff = cohort_layout(eff)
+            # fold 105/1: per-slot injection coins over the popped cohort
+            fstate, eff = stage.on_pop(fstate, k_sel, idx, valid)
             new_clock = jnp.maximum(clock, jnp.max(jnp.where(valid, t_ev, -jnp.inf)))
             # an all-idle fleet inside availability gaps must not freeze the
             # clock: with nothing in flight to pop, jump to the earliest
@@ -581,7 +481,6 @@ def _make_async_step(
             disp_params = cohort_layout(
                 jax.tree.map(lambda h: h[read_ver % H], state["hist"])
             )
-            shards = cohort_layout(gather_rows(data, idx, task.client_data))
             keys = jax.random.split(k_local, B)
             if cohort_pad:
                 # the first B keys must stay the exact draws of the unpadded
@@ -589,33 +488,12 @@ def _make_async_step(
                 # reuse the last real key — their updates carry weight 0
                 keys = keys[jnp.minimum(jnp.arange(Bp), B - 1)]
             lr = lr_fn(jnp.maximum(disp_ver, 0))
-            updated, losses = cohort_layout(
-                jax.vmap(local_update, in_axes=(0, 0, 0, 0))(
-                    disp_params, shards, keys, lr
-                )
-            )
-            if have_faults and (faults.has("scale") or faults.has("noise")):
-                # fold 105/2: corruption noise. Missed slots keep their exact
-                # input buffers (per-slot where inside corrupt_updates), so a
-                # rate-0 set is bitwise identity
-                updated = corrupt_updates(
-                    updated, disp_params, eff, jax.random.fold_in(k_fault, 2),
-                    faults.has("scale"), faults.has("noise"),
-                )
-            if collude_on:
-                # after corrupt: a coalition member's replacement is
-                # authoritative over any scale/noise it also drew. Keyless —
-                # the direction is a trace-time constant, the jitter rode
-                # the fault's own pop fold
-                updated = collude_updates(updated, disp_params, eff)
 
         with jax.named_scope("aggregate"):
-            # --- buffered aggregation of deltas through the aggregator seam
-            succ = valid & ~ev["dropped"][idx]
-            if kill_on:
-                # mid-round dropout: the update never arrived — excluded from
-                # aggregation and from heartbeat contact below
-                succ = succ & ~eff.kill
+            # --- the updates that reach the server: a dropout fault's
+            # mid-round kill is excluded from aggregation and from
+            # heartbeat contact below
+            succ = stage.delivered(valid & ~ev["dropped"][idx], eff)
             if hb_timeout > 0:
                 # an update landing more than the timeout after its client's
                 # last contact looks dead to its tier coordinator: excluded
@@ -625,48 +503,30 @@ def _make_async_step(
                     hb["last_beat"][idx], t_ev, hb_timeout
                 )
                 succ = succ & ~dark
-                arrived = valid & ~eff.kill if kill_on else valid
-                hb = hb_mod.beat_at(hb, ev_mod.scatter_idx(idx, arrived), t_ev)
-            staleness = jnp.maximum(version - disp_ver, 0)
-            if have_def:
-                # fold 108: the defense tier's dedicated key (sub-folds
-                # 0 probation / 1 readmit coins). Every update that arrived
-                # (pre-exclusion succ) is scored — including probation
-                # clients — then post-transition suspects are excluded from
-                # the reduction through the exact seam heartbeat dark
-                # clients use, closing the detect->quarantine loop within
-                # the step
-                dstate, suspect, w_scale = defense.observe(
-                    dstate, jax.random.fold_in(k_sel, 108),
-                    updated, disp_params, idx, succ, staleness,
-                    losses=losses, ages=cohort_layout(sched["ages"][idx]),
-                    labels=cohort_layout(effects_hit(eff)) if sup_on else None,
+                hb = hb_mod.beat_at(
+                    hb, ev_mod.scatter_idx(idx, stage.delivered(valid, eff)),
+                    t_ev,
                 )
-                succ = succ & ~cohort_layout(suspect[idx])
-            w = agg.weigh(succ, staleness)
-            if col_on:
-                # clique members keep a (discounted) vote rather than a
-                # binary exclusion: w_scale is exact 1.0 on clique-free
-                # slots, so a calm armed run multiplies by ones
-                w = w * w_scale
+            staleness = jnp.maximum(version - disp_ver, 0)
+
+        # --- local training from each client's dispatch-time model, then
+        # buffered aggregation of the deltas
+        out = stage(state["params"], disp_params, data, idx, keys, lr, None,
+                    succ, staleness, eff, state.get("defense"), k_sel,
+                    sched["ages"])
+        succ, w, params = out.arrived, out.weights, out.params
+
+        with jax.named_scope("aggregate"):
             wsum = w.sum()
             has = wsum > 0
-            denom = jnp.maximum(wsum, 1e-9)
-            if mtd_on:
-                params, agg_tel = aggregate_mtd(
-                    state["params"], updated, disp_params, w, idx,
-                    dstate["level"],
-                )
-            else:
-                params, agg_tel = aggregate(
-                    state["params"], updated, disp_params, w, idx
-                )
             version = version + has.astype(jnp.int32)
             hist = jax.tree.map(
                 lambda h, p: h.at[version % H].set(p), state["hist"], params
             )
             # NaN, not a fake 0.0 datapoint, when nothing was aggregated
-            mean_loss = jnp.where(has, jnp.sum(losses * w) / denom, jnp.nan)
+            mean_loss = jnp.where(
+                has, jnp.sum(out.losses * w) / jnp.maximum(wsum, 1e-9), jnp.nan
+            )
 
         with jax.named_scope("load_metric"):
             # --- completed clients go idle; wall-clock AoI samples
@@ -716,7 +576,7 @@ def _make_async_step(
                 stats["redispatched"] = state["stats"]["redispatched"] + rd_retried
                 stats["rd_expired"] = state["stats"]["rd_expired"] + rd_expired
             for s in agg.stat_names:
-                stats[f"agg_{s}"] = state["stats"][f"agg_{s}"] + agg_tel[s]
+                stats[f"agg_{s}"] = state["stats"][f"agg_{s}"] + out.stats[s]
             new_state = {
                 **state,
                 "params": params, "hist": hist, "sched": sched, "ev": ev,
@@ -727,7 +587,7 @@ def _make_async_step(
             if have_faults:
                 new_state["faults"] = fstate
             if have_def:
-                new_state["defense"] = dstate
+                new_state["defense"] = out.dstate
             if rd_on:
                 new_state["rd"] = rd
             if tiered:

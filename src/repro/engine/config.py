@@ -1,8 +1,8 @@
 """One run contract for synchronous and asynchronous federated training.
 
-``RunConfig`` absorbs the old ``FLConfig`` + ``AsyncConfig`` pair: every
-field the sync round loop and the event-driven async loop need, plus the
-registry names (and kwargs) of the selection policy and the aggregator.
+``RunConfig`` holds every field the sync round loop and the event-driven
+async loop need, plus the registry names (and kwargs) of the selection
+policy and the aggregator.
 ``RunResult`` / ``RoundRecord`` are the typed output schema both engines
 emit identically; ``repro.engine.serialize.to_jsonable`` is the one
 JSON-safe serializer for all of it (NaN -> null, numpy -> builtin).
@@ -408,33 +408,6 @@ def default_cohort_width(n_clients: int, k: int) -> int:
     return min(n_clients, int(k + 4 * sigma) + 1)
 
 
-def run_config_from_legacy(fl, acfg=None, **overrides) -> RunConfig:
-    """Build a RunConfig from the legacy ``FLConfig`` (+ ``AsyncConfig``)
-    pair. ``acfg`` switches the mode to async and maps its staleness
-    knobs onto the fedbuff aggregator's kwargs."""
-    kw: Dict[str, Any] = dict(
-        n_clients=fl.n_clients, k=fl.k, m=fl.m, policy=fl.policy,
-        rounds=fl.rounds, local_epochs=fl.local_epochs,
-        batch_size=fl.batch_size, lr0=fl.lr0, lr_decay=fl.lr_decay,
-        seed=fl.seed, max_cohort=fl.max_cohort, eval_every=fl.eval_every,
-    )
-    if acfg is not None:
-        kw.update(
-            mode="async",
-            aggregator="fedbuff",
-            aggregator_kwargs={
-                "staleness_mode": acfg.staleness_mode,
-                "staleness_exp": acfg.staleness_exp,
-            },
-            buffer_size=acfg.buffer_size,
-            max_versions=acfg.max_versions,
-            profile=acfg.profile,
-            use_kernel=acfg.use_kernel,
-        )
-    kw.update(overrides)
-    return RunConfig(**kw)
-
-
 # ---------------------------------------------------------------------------
 # Result schema
 # ---------------------------------------------------------------------------
@@ -495,3 +468,12 @@ class RunResult:
             "wall_stats": self.wall_stats,
             "wall_time_s": self.wall_time_s,
         })
+
+
+def rounds_to_target(history: Dict, target_acc: float) -> Optional[int]:
+    """First round at which eval accuracy reaches the target (paper's
+    convergence-speed metric), from a ``RunResult.history()``."""
+    for r, a in zip(history["round"], history["accuracy"]):
+        if a >= target_acc:
+            return r
+    return None

@@ -56,8 +56,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import distributed as dist
 from repro.core.selection import Policy
-from repro.engine.aggregators import Aggregator, cohort_sharded_apply
+from repro.engine.aggregators import Aggregator
 from repro.engine.async_engine import AsyncEngine, _make_async_step
+from repro.engine.cohort import cohort_layout, make_aggregate
 from repro.engine.config import RunConfig
 from repro.fl.task import FLTask
 from repro.sim import events as ev_mod
@@ -220,7 +221,6 @@ class ShardedAsyncEngine(AsyncEngine):
             self.mesh, cfg.n_clients, cfg.resolved_buffer_size(),
             axis=self.fleet_axis,
         )
-        rep = NamedSharding(self.mesh, P())
 
         def pop(ev):
             t, idx = next_k(ev["t_done"])
@@ -235,57 +235,23 @@ class ShardedAsyncEngine(AsyncEngine):
                 ),
             )
 
-        if cfg.shard_cohort:
-            # cohort-parallel: (B,) intermediates lay out over the mesh —
-            # each device gathers, trains, and accumulates only its
-            # B/devices cohort slice; the aggregator merges with one psum
-            # of the accumulator pytree (allclose, not bitwise, to the
-            # replicated layout: cross-device reduction order differs)
-            cohort = NamedSharding(self.mesh, P(self.fleet_axis))
-
-            def cohort_layout(tree):
-                return jax.tree.map(
-                    lambda x: jax.lax.with_sharding_constraint(x, cohort),
-                    tree,
-                )
-
-            if self.topo is not None and not self.topo.is_star:
-                # the tiered reduction in cohort-parallel form: slot
-                # accumulation + the tier-0 segment sum run shard-locally
-                # inside the same shard_map-and-one-psum pattern
-                from repro.topo.reduce import tiered_apply
-
-                aggregate = tiered_apply(
-                    self.aggregator, self.topo, cfg.n_clients,
-                    mesh=self.mesh, axis=self.fleet_axis,
-                )
-            else:
-                aggregate = cohort_sharded_apply(
-                    self.aggregator, self.mesh, self.fleet_axis
-                )
-            return _make_async_step(
-                self.task, cfg, self.policy, self.aggregator, self.profile,
-                pop=pop, cohort_layout=cohort_layout,
-                constrain_state=constrain_state,
-                aggregate=aggregate,
-                cohort_pad=dist.cohort_padding(
-                    cfg.resolved_buffer_size(), self.mesh_shards
-                ),
-                topo=self.topo, faults=self.fault_set,
-                defense=self.defense,
-            )
-
-        # bit-exact default: cohort-sized (B,) intermediates pinned to a
-        # replicated layout so reduction order cannot drift from the
-        # single-device engine
-        def replicate(tree):
-            return jax.tree.map(
-                lambda x: jax.lax.with_sharding_constraint(x, rep), tree
-            )
-
+        # cohort-parallel: (B,) intermediates lay out over the mesh — each
+        # device gathers, trains, and accumulates only its B/devices cohort
+        # slice, and the aggregate merges by one psum (allclose, not
+        # bitwise, to the replicated layout: cross-device reduction order
+        # differs). Otherwise they stay replicated, bit-exact to one device
+        axis = self.fleet_axis if cfg.shard_cohort else None
         return _make_async_step(
             self.task, cfg, self.policy, self.aggregator, self.profile,
-            pop=pop, cohort_layout=replicate, constrain_state=constrain_state,
+            pop=pop, cohort_layout=cohort_layout(self.mesh, axis),
+            constrain_state=constrain_state,
+            aggregate=make_aggregate(
+                self.aggregator, self.topo, cfg.n_clients,
+                self.mesh if axis else None, axis,
+            ),
+            cohort_pad=dist.cohort_padding(
+                cfg.resolved_buffer_size(), self.mesh_shards
+            ) if axis else 0,
             topo=self.topo, faults=self.fault_set, defense=self.defense,
         )
 

@@ -1,3 +1,1 @@
-from repro.fl.config import FLConfig  # noqa: F401
-from repro.fl.rounds import make_round_fn, rounds_to_target, run_training  # noqa: F401
 from repro.fl.task import FLTask, make_cnn_task, make_lm_task  # noqa: F401

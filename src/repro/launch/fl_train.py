@@ -22,8 +22,7 @@ import argparse
 
 from repro.compile_cache import enable_compile_cache
 from repro.core import load_metric
-from repro.engine import RunConfig, SyncEngine, run_engine
-from repro.fl.rounds import rounds_to_target
+from repro.engine import RunConfig, SyncEngine, rounds_to_target, run_engine
 from repro.launch._fl_cli import (
     add_common_args,
     build_run_config,

@@ -1,12 +1,12 @@
 """Event-driven asynchronous fleet simulator.
 
-Advances a simulated wall clock over an arbitrarily large client fleet and
-drives buffered asynchronous federated training (FedBuff-style) with
-staleness-aware aggregation. The paper's load metric (Var[X], AoI) is
-measured here in *simulated seconds* rather than round index, which is
-where its fairness and no-coordination claims become systems claims:
-stragglers, dropouts, and availability windows all shift the realized
-selection process.
+Advances a simulated wall clock over an arbitrarily large client fleet:
+latency profiles, the completion-event state and its pop, which the
+async engine (``repro.engine.async_engine``) steps. The paper's load
+metric (Var[X], AoI) is measured here in *simulated seconds* rather than
+round index, which is where its fairness and no-coordination claims
+become systems claims: stragglers, dropouts, and availability windows all
+shift the realized selection process.
 """
 from repro.sim.arrivals import (  # noqa: F401
     ArrivalProcess,
@@ -27,9 +27,4 @@ from repro.sim.events import (  # noqa: F401
     init_event_state,
     next_k_events,
     schedule_completions,
-)
-from repro.sim.async_rounds import (  # noqa: F401
-    AsyncConfig,
-    run_async_training,
-    staleness_weight,
 )

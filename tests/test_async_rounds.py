@@ -8,8 +8,10 @@ import pytest
 
 from repro.configs.paper_cnn import MNIST_CNN
 from repro.data.synthetic import make_image_dataset
-from repro.fl import FLConfig, make_cnn_task, run_training
-from repro.sim import AsyncConfig, get_profile, run_async_training, staleness_weight
+from repro.engine import RunConfig, make_engine, run_engine
+from repro.engine.aggregators import staleness_weight
+from repro.fl import make_cnn_task
+from repro.sim import get_profile
 
 SMALL_CNN = dataclasses.replace(
     MNIST_CNN, name="paper-cnn-mnist-small", image_size=16,
@@ -25,13 +27,18 @@ def small_task():
     return make_cnn_task(SMALL_CNN, train, test, n_clients=20)
 
 
-def _fl(policy, rounds=6, **kw):
+def _cfg(policy, rounds=6, **kw):
     base = dict(
         n_clients=20, k=4, m=6, policy=policy, rounds=rounds,
         local_epochs=2, batch_size=10, eval_every=2,
     )
     base.update(kw)
-    return FLConfig(**base)
+    return RunConfig(**base)
+
+
+def _async(task, cfg, **kw):
+    return run_engine(make_engine(task, dataclasses.replace(
+        cfg, mode="async", **kw)))
 
 
 # ---------------------------------------------------------------------------
@@ -61,44 +68,37 @@ def test_staleness_weights_sum_in_aggregation(small_task):
     """Under a heterogeneous profile the realized weights are normalized:
     each aggregation advances exactly one version and the loop reports one
     successful update per buffered completion (no double counting)."""
-    fl = _fl("markov", rounds=10)
-    out = run_async_training(
-        small_task, fl, AsyncConfig(buffer_size=4, profile="lognormal")
-    )
-    ws = out["wall_stats"]
-    assert 0 < ws["aggregations"] <= fl.rounds
-    assert ws["updates_applied"] <= fl.rounds * 4
-    assert out["history"]["version"][-1] == ws["aggregations"]
+    cfg = _cfg("markov", rounds=10)
+    out = _async(small_task, cfg, buffer_size=4, profile="lognormal")
+    ws = out.wall_stats
+    assert 0 < ws["aggregations"] <= cfg.rounds
+    assert ws["updates_applied"] <= cfg.rounds * 4
+    assert out.history()["version"][-1] == ws["aggregations"]
     assert ws["mean_staleness"] >= 0.0
     assert ws["max_staleness"] >= ws["mean_staleness"]
     # params actually moved
-    assert out["history"]["train_loss"][-1] > 0
+    assert out.history()["train_loss"][-1] > 0
 
 
 def test_dropouts_reduce_applied_updates(small_task):
-    fl = _fl("markov", rounds=10)
-    drop = run_async_training(
-        small_task, fl,
-        AsyncConfig(buffer_size=4,
-                    profile=dataclasses.replace(get_profile("lognormal"), dropout=0.6)),
+    cfg = _cfg("markov", rounds=10)
+    drop = _async(
+        small_task, cfg, buffer_size=4,
+        profile=dataclasses.replace(get_profile("lognormal"), dropout=0.6),
     )
-    clean = run_async_training(
-        small_task, fl, AsyncConfig(buffer_size=4, profile="lognormal")
-    )
-    assert drop["wall_stats"]["updates_applied"] < clean["wall_stats"]["updates_applied"]
+    clean = _async(small_task, cfg, buffer_size=4, profile="lognormal")
+    assert drop.wall_stats["updates_applied"] < clean.wall_stats["updates_applied"]
 
 
 def test_all_idle_fleet_does_not_freeze_clock(small_task):
     """With long availability gaps and a buffer that drains the whole
     fleet, one step leaves everyone idle inside their off-window; the
     clock must jump to the next window opening instead of deadlocking."""
-    fl = _fl("random", rounds=8, k=20)
+    cfg = _cfg("random", rounds=8, k=20)
     prof = dataclasses.replace(get_profile("uniform"), avail_gap=50.0)
-    out = run_async_training(
-        small_task, fl,
-        AsyncConfig(buffer_size=fl.n_clients, staleness_mode="const", profile=prof),
-    )
-    ws = out["wall_stats"]
+    out = _async(small_task, cfg, buffer_size=cfg.n_clients,
+                 aggregator_kwargs={"staleness_mode": "const"}, profile=prof)
+    ws = out.wall_stats
     # without the clock jump the run freezes after the first aggregation
     # at sim_time == 1.0 (the one unit-latency cohort)
     assert ws["aggregations"] >= 2
@@ -111,42 +111,34 @@ def test_all_idle_fleet_does_not_freeze_clock(small_task):
 
 
 def test_degenerate_profile_matches_sync_fedavg(small_task):
-    fl = _fl("random", rounds=6)
-    sync = run_training(small_task, fl)
-    asy = run_async_training(
-        small_task, fl,
-        AsyncConfig(buffer_size=fl.k, staleness_mode="const", profile="uniform"),
-    )
+    cfg = _cfg("random", rounds=6)
+    sync = run_engine(make_engine(small_task, cfg))
+    asy = _async(small_task, cfg, buffer_size=cfg.k,
+                 aggregator_kwargs={"staleness_mode": "const"},
+                 profile="uniform")
     # identical realized cohorts round for round
-    np.testing.assert_array_equal(sync["selection"], np.asarray(asy["selection"]))
+    np.testing.assert_array_equal(sync.selection, np.asarray(asy.selection))
     # per-update losses and eval trajectory match within float tolerance
-    np.testing.assert_allclose(
-        sync["history"]["train_loss"], asy["history"]["train_loss"], rtol=1e-4
-    )
-    np.testing.assert_allclose(
-        sync["history"]["eval_loss"], asy["history"]["eval_loss"], rtol=1e-4
-    )
-    np.testing.assert_allclose(
-        sync["history"]["accuracy"], asy["history"]["accuracy"], atol=1e-3
-    )
-    ws = asy["wall_stats"]
+    hs, ha = sync.history(), asy.history()
+    np.testing.assert_allclose(hs["train_loss"], ha["train_loss"], rtol=1e-4)
+    np.testing.assert_allclose(hs["eval_loss"], ha["eval_loss"], rtol=1e-4)
+    np.testing.assert_allclose(hs["accuracy"], ha["accuracy"], atol=1e-3)
+    ws = asy.wall_stats
     assert ws["mean_staleness"] == 0.0 and ws["max_staleness"] == 0
-    assert ws["aggregations"] == fl.rounds
+    assert ws["aggregations"] == cfg.rounds
     # one unit-latency cohort per step: simulated clock counts the steps
-    assert ws["sim_time"] == pytest.approx(fl.rounds)
+    assert ws["sim_time"] == pytest.approx(cfg.rounds)
 
 
 def test_degenerate_markov_policy_also_reduces(small_task):
     """Same reduction with the paper's Markov policy (variable cohorts):
     buffer >= max cohort drains every completion each step, so version
     lags never appear."""
-    fl = _fl("markov", rounds=8)
-    asy = run_async_training(
-        small_task, fl,
-        AsyncConfig(buffer_size=fl.n_clients, staleness_mode="const",
-                    profile="uniform"),
-    )
-    ws = asy["wall_stats"]
+    cfg = _cfg("markov", rounds=8)
+    asy = _async(small_task, cfg, buffer_size=cfg.n_clients,
+                 aggregator_kwargs={"staleness_mode": "const"},
+                 profile="uniform")
+    ws = asy.wall_stats
     assert ws["max_staleness"] == 0
     # empirical epoch-indexed X sees the same chain the sync loop would
-    assert ws["mean_X_epoch"] == pytest.approx(fl.n_clients / fl.k, rel=0.5)
+    assert ws["mean_X_epoch"] == pytest.approx(cfg.n_clients / cfg.k, rel=0.5)

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.engine.async_engine import client_rows, gather_rows
+from repro.engine.cohort import client_rows, gather_rows
 
 N = 12
 

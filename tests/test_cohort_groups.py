@@ -1,4 +1,4 @@
-"""The sync round's cohort group loop (``engine.sync._train_groups``).
+"""The sync round's cohort group loop (``engine.cohort._train_groups``).
 
 A variable-size policy's cohort is packed into a prefix of
 ``cfg.cohort_width()`` slots (``fl.server.cohort_indices``), and on one
@@ -21,9 +21,9 @@ from repro.configs.paper_cnn import MNIST_CNN
 from repro.core.selection import Policy, make_policy
 from repro.data.synthetic import make_image_dataset
 from repro.engine import RunConfig, SyncEngine, run_engine
-from repro.engine import sync
+from repro.engine import cohort, sync
+from repro.engine.cohort import _group_size, _train_groups
 from repro.engine.registry import make_aggregator
-from repro.engine.sync import _group_size, _train_groups
 from repro.fl import make_cnn_task
 from repro.fl.client import make_local_update
 from repro.fl.server import cohort_indices
@@ -115,11 +115,11 @@ def test_train_groups_matches_one_full_vmap_on_selected_slots(small_task, count,
     lr = jnp.float32(cfg.lr0)
     data = small_task.client_data
 
-    grouped = jax.jit(_train_groups, static_argnums=(0, 7))
-    up, losses, trained = grouped(local_update, params, data, idx, keys, lr,
-                                  jnp.int32(count), group)
-    up1, losses1, trained1 = grouped(local_update, params, data, idx, keys,
-                                     lr, jnp.int32(count), WIDTH)
+    grouped = jax.jit(_train_groups, static_argnums=(0, 8))
+    up, losses, trained = grouped(local_update, params, data, data, idx, keys,
+                                  lr, jnp.int32(count), group)
+    up1, losses1, trained1 = grouped(local_update, params, data, data, idx,
+                                     keys, lr, jnp.int32(count), WIDTH)
     full_up, full_losses = jax.jit(jax.vmap(
         local_update, in_axes=(None, 0, 0, None)))(
         params, jax.tree.map(lambda a: a[idx], data), keys, lr)
@@ -179,7 +179,7 @@ def test_grouped_round_equals_one_group_round(small_task, monkeypatch):
     grouped = _run_core(small_task, cfg, policy, len(COUNTS))
     vmap = _run_core(small_task, cfg, policy, len(COUNTS),
                      cohort_layout=_identity)
-    monkeypatch.setattr(sync, "_group_size", lambda width: width)
+    monkeypatch.setattr(cohort, "_group_size", lambda width: width)
     one = _run_core(small_task, cfg, policy, len(COUNTS))
     for r, (got, o, v) in enumerate(zip(grouped, one, vmap)):
         (p, s, l, t), (p1, s1, l1, t1), (pv, sv, lv, tv) = got, o, v

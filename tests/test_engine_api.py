@@ -26,12 +26,12 @@ from repro.engine import (
     policy_names,
     register_aggregator,
     register_policy,
-    run_config_from_legacy,
+    rounds_to_target,
     run_engine,
     to_jsonable,
 )
 from repro.engine.aggregators import Aggregator
-from repro.fl import FLConfig, make_cnn_task
+from repro.fl import make_cnn_task
 
 SMALL_CNN = dataclasses.replace(
     MNIST_CNN, name="paper-cnn-mnist-small", image_size=16,
@@ -199,7 +199,7 @@ def test_fedprox_damps_updates(small_task):
 
 
 # ---------------------------------------------------------------------------
-# RunConfig validation + legacy conversion
+# RunConfig validation
 # ---------------------------------------------------------------------------
 
 
@@ -213,33 +213,12 @@ def test_run_config_validates_mode_and_k():
 def test_max_cohort_below_k_rejected():
     with pytest.raises(ValueError, match="max_cohort"):
         RunConfig(n_clients=100, k=15, max_cohort=10)
-    with pytest.raises(ValueError, match="max_cohort"):
-        FLConfig(n_clients=100, k=15, max_cohort=10)
 
 
 def test_cohort_width_default_padding():
     cfg = RunConfig(n_clients=100, k=15)
-    fl = FLConfig(n_clients=100, k=15)
-    assert cfg.cohort_width() == fl.cohort_width()
     assert 15 < cfg.cohort_width() <= 100
     assert RunConfig(n_clients=100, k=15, max_cohort=40).cohort_width() == 40
-
-
-def test_run_config_from_legacy_roundtrip():
-    from repro.sim import AsyncConfig
-
-    fl = FLConfig(n_clients=30, k=5, m=8, policy="oldest_age", rounds=7,
-                  seed=3, eval_every=2)
-    cfg = run_config_from_legacy(fl)
-    assert cfg.mode == "sync" and cfg.resolved_aggregator() == "fedavg"
-    assert (cfg.n_clients, cfg.k, cfg.m, cfg.rounds) == (30, 5, 8, 7)
-
-    acfg = AsyncConfig(buffer_size=3, staleness_mode="poly",
-                       staleness_exp=0.9, max_versions=4, profile="mobile")
-    cfg = run_config_from_legacy(fl, acfg)
-    assert cfg.mode == "async" and cfg.resolved_aggregator() == "fedbuff"
-    assert cfg.aggregator_kwargs["staleness_exp"] == 0.9
-    assert cfg.resolved_buffer_size() == 3 and cfg.max_versions == 4
 
 
 # ---------------------------------------------------------------------------
@@ -272,3 +251,10 @@ def test_run_result_jsonable(small_task):
     assert back["config"]["policy"] == "markov"
     assert back["history"]["round"] == [3]
     assert back["wall_stats"] is None
+
+
+def test_rounds_to_target_reads_the_history():
+    history = {"round": [2, 4, 6, 8], "accuracy": [0.1, 0.55, 0.5, 0.7]}
+    assert rounds_to_target(history, 0.5) == 4
+    assert rounds_to_target(history, 0.7) == 8
+    assert rounds_to_target(history, 0.9) is None

@@ -21,7 +21,7 @@ from repro.configs.paper_cnn import MNIST_CNN
 from repro.core.selection import Policy
 from repro.data.synthetic import make_image_dataset
 from repro.engine import AsyncEngine, RunConfig, SyncEngine, run_engine
-from repro.engine.async_engine import client_rows
+from repro.engine.cohort import client_rows
 from repro.engine.config import chunk_plan
 
 SMALL_CNN = dataclasses.replace(

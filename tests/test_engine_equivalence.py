@@ -1,5 +1,5 @@
 """Golden seed-equivalence: the unified engines must reproduce the
-pre-refactor ``run_training`` / ``run_async_training`` loops bit-for-bit.
+pre-refactor sync and async round loops bit-for-bit.
 
 The reference implementations below are frozen copies of the round loops
 as they stood before the ``repro.engine`` refactor (PR 1), with the
@@ -17,15 +17,20 @@ import pytest
 from repro.configs.paper_cnn import MNIST_CNN
 from repro.core.selection import make_policy
 from repro.data.synthetic import make_image_dataset
-from repro.engine import AsyncEngine, RunConfig, SyncEngine, run_engine
-from repro.fl import FLConfig, make_cnn_task, run_training
+from repro.engine import (
+    AsyncEngine,
+    RunConfig,
+    SyncEngine,
+    make_engine,
+    run_engine,
+)
+from repro.engine.aggregators import staleness_weight
+from repro.fl import make_cnn_task
 from repro.fl.client import make_local_update
 from repro.fl.server import broadcast_to_cohort, cohort_indices, fedavg_aggregate
 from repro.optim.schedules import exponential_decay
-from repro.sim import AsyncConfig, run_async_training
 from repro.sim import events as ev_mod
 from repro.sim import latency as lat_mod
-from repro.sim.async_rounds import staleness_weight
 
 SMALL_CNN = dataclasses.replace(
     MNIST_CNN, name="paper-cnn-mnist-small", image_size=16,
@@ -41,13 +46,13 @@ def small_task():
     return make_cnn_task(SMALL_CNN, train, test, n_clients=20)
 
 
-def _fl(policy, rounds=5, **kw):
+def _cfg(policy, rounds=5, **kw):
     base = dict(
         n_clients=20, k=4, m=6, policy=policy, rounds=rounds,
         local_epochs=2, batch_size=10, eval_every=1,
     )
     base.update(kw)
-    return FLConfig(**base)
+    return RunConfig(**base)
 
 
 def _assert_trees_equal(a, b):
@@ -60,13 +65,13 @@ def _assert_trees_equal(a, b):
 # ---------------------------------------------------------------------------
 
 
-def _reference_sync_run(task, fl):
-    policy = make_policy(fl.policy, fl.n_clients, fl.k, fl.m)
-    width = fl.cohort_width() if not policy.exact_k else fl.k
+def _reference_sync_run(task, cfg):
+    policy = make_policy(cfg.policy, cfg.n_clients, cfg.k, cfg.m)
+    width = cfg.cohort_width() if not policy.exact_k else cfg.k
     local_update = make_local_update(
-        task.loss_fn, fl.local_epochs, fl.batch_size, task.examples_per_client
+        task.loss_fn, cfg.local_epochs, cfg.batch_size, task.examples_per_client
     )
-    lr_fn = exponential_decay(fl.lr0, fl.lr_decay)
+    lr_fn = exponential_decay(cfg.lr0, cfg.lr_decay)
 
     @jax.jit
     def round_fn(params, sched_state, key):
@@ -84,13 +89,13 @@ def _reference_sync_run(task, fl):
         mean_loss = jnp.sum(losses * weights) / jnp.maximum(weights.sum(), 1.0)
         return params, sched_state, selected, mean_loss
 
-    key = jax.random.PRNGKey(fl.seed)
+    key = jax.random.PRNGKey(cfg.seed)
     k_init, k_policy, k_run = jax.random.split(key, 3)
     params = task.init(k_init)
-    sched_state = policy.init(k_policy, fl.n_clients)
-    sel_hist = np.zeros((fl.rounds, fl.n_clients), dtype=bool)
+    sched_state = policy.init(k_policy, cfg.n_clients)
+    sel_hist = np.zeros((cfg.rounds, cfg.n_clients), dtype=bool)
     losses = []
-    for r in range(fl.rounds):
+    for r in range(cfg.rounds):
         params, sched_state, selected, loss = round_fn(
             params, sched_state, jax.random.fold_in(k_run, r)
         )
@@ -101,22 +106,17 @@ def _reference_sync_run(task, fl):
 
 @pytest.mark.parametrize("policy", ["markov", "random"])
 def test_sync_engine_matches_prerefactor_loop(small_task, policy):
-    fl = _fl(policy)
-    ref_params, ref_sel, ref_losses = _reference_sync_run(small_task, fl)
-    out = run_training(small_task, fl)
-    np.testing.assert_array_equal(out["selection"], ref_sel)
-    np.testing.assert_array_equal(out["history"]["train_loss"], ref_losses)
-    _assert_trees_equal(out["params"], ref_params)
+    cfg = _cfg(policy)
+    ref_params, ref_sel, ref_losses = _reference_sync_run(small_task, cfg)
+    res = run_engine(make_engine(small_task, cfg))
+    np.testing.assert_array_equal(res.selection, ref_sel)
+    np.testing.assert_array_equal(res.history()["train_loss"], ref_losses)
+    _assert_trees_equal(res.params, ref_params)
 
 
 def test_sync_engine_direct_api_matches_prerefactor_loop(small_task):
-    fl = _fl("markov")
-    ref_params, ref_sel, ref_losses = _reference_sync_run(small_task, fl)
-    cfg = RunConfig(
-        n_clients=fl.n_clients, k=fl.k, m=fl.m, policy=fl.policy,
-        rounds=fl.rounds, local_epochs=fl.local_epochs,
-        batch_size=fl.batch_size, eval_every=1,
-    )
+    cfg = _cfg("markov")
+    ref_params, ref_sel, ref_losses = _reference_sync_run(small_task, cfg)
     res = run_engine(SyncEngine(small_task, cfg))
     np.testing.assert_array_equal(res.selection, ref_sel)
     np.testing.assert_array_equal(
@@ -130,16 +130,20 @@ def test_sync_engine_direct_api_matches_prerefactor_loop(small_task):
 # ---------------------------------------------------------------------------
 
 
-def _reference_async_run(task, fl, acfg):
-    policy = make_policy(fl.policy, fl.n_clients, fl.k, fl.m)
-    n = fl.n_clients
-    B = acfg.buffer_size or fl.k
-    H = acfg.max_versions
-    profile = acfg.resolved_profile()
+def _reference_async_run(task, cfg):
+    policy = make_policy(cfg.policy, cfg.n_clients, cfg.k, cfg.m)
+    n = cfg.n_clients
+    B = cfg.resolved_buffer_size()
+    H = cfg.max_versions
+    profile = cfg.profile
+    if isinstance(profile, str):
+        profile = lat_mod.get_profile(profile)
+    staleness_mode = cfg.aggregator_kwargs.get("staleness_mode", "poly")
+    staleness_exp = cfg.aggregator_kwargs.get("staleness_exp", 0.5)
     local_update = make_local_update(
-        task.loss_fn, fl.local_epochs, fl.batch_size, task.examples_per_client
+        task.loss_fn, cfg.local_epochs, cfg.batch_size, task.examples_per_client
     )
-    lr_fn = exponential_decay(fl.lr0, fl.lr_decay)
+    lr_fn = exponential_decay(cfg.lr0, cfg.lr_decay)
 
     def init_state(params, sched_state, key):
         return {
@@ -176,7 +180,7 @@ def _reference_async_run(task, fl, acfg):
         dropped = lat_mod.sample_dropout(k_drop, profile, n)
         ev = ev_mod.schedule_completions(ev, send, clock, latency, version, dropped)
 
-        t_ev, idx, valid, ev = ev_mod.pop_events(ev, B, use_kernel=acfg.use_kernel)
+        t_ev, idx, valid, ev = ev_mod.pop_events(ev, B, use_kernel=cfg.use_kernel)
         new_clock = jnp.maximum(clock, jnp.max(jnp.where(valid, t_ev, -jnp.inf)))
         new_clock = jnp.where(
             valid.any(), new_clock,
@@ -196,7 +200,7 @@ def _reference_async_run(task, fl, acfg):
         succ = valid & ~ev["dropped"][idx]
         staleness = jnp.maximum(version - disp_ver, 0)
         w = succ.astype(jnp.float32) * staleness_weight(
-            staleness, acfg.staleness_mode, acfg.staleness_exp
+            staleness, staleness_mode, staleness_exp
         )
         wsum = w.sum()
         has = wsum > 0
@@ -235,14 +239,14 @@ def _reference_async_run(task, fl, acfg):
         }
         return state, {"send": send, "loss": mean_loss}
 
-    key = jax.random.PRNGKey(fl.seed)
+    key = jax.random.PRNGKey(cfg.seed)
     k_init, k_policy, k_run = jax.random.split(key, 3)
     params = task.init(k_init)
-    sched = policy.init(k_policy, fl.n_clients)
+    sched = policy.init(k_policy, cfg.n_clients)
     state = init_state(params, sched, jax.random.fold_in(k_run, 2**31))
-    sel_hist = np.zeros((fl.rounds, fl.n_clients), dtype=bool)
+    sel_hist = np.zeros((cfg.rounds, cfg.n_clients), dtype=bool)
     losses = []
-    for s in range(fl.rounds):
+    for s in range(cfg.rounds):
         state, aux = step(state, jax.random.fold_in(k_run, s))
         sel_hist[s] = np.asarray(aux["send"])
         losses.append(float(aux["loss"]))
@@ -250,29 +254,22 @@ def _reference_async_run(task, fl, acfg):
 
 
 def test_async_engine_matches_prerefactor_loop(small_task):
-    fl = _fl("markov")
-    acfg = AsyncConfig(buffer_size=4, profile="lognormal")
-    ref_params, ref_sel, ref_losses = _reference_async_run(small_task, fl, acfg)
-    out = run_async_training(small_task, fl, acfg)
-    np.testing.assert_array_equal(np.asarray(out["selection"]), ref_sel)
-    np.testing.assert_array_equal(out["history"]["train_loss"], ref_losses)
-    _assert_trees_equal(out["params"], ref_params)
+    cfg = _cfg("markov", mode="async", buffer_size=4, profile="lognormal")
+    ref_params, ref_sel, ref_losses = _reference_async_run(small_task, cfg)
+    res = run_engine(make_engine(small_task, cfg))
+    np.testing.assert_array_equal(np.asarray(res.selection), ref_sel)
+    np.testing.assert_array_equal(res.history()["train_loss"], ref_losses)
+    _assert_trees_equal(res.params, ref_params)
 
 
 def test_async_engine_with_dropout_matches_prerefactor_loop(small_task):
-    fl = _fl("random", rounds=6)
     prof = dataclasses.replace(lat_mod.get_profile("mobile"), dropout=0.3)
-    acfg = AsyncConfig(buffer_size=3, staleness_mode="poly",
-                       staleness_exp=0.7, max_versions=4, profile=prof)
-    ref_params, ref_sel, ref_losses = _reference_async_run(small_task, fl, acfg)
-    cfg = RunConfig(
-        n_clients=fl.n_clients, k=fl.k, m=fl.m, policy=fl.policy,
-        rounds=fl.rounds, local_epochs=fl.local_epochs,
-        batch_size=fl.batch_size, eval_every=1, mode="async",
-        aggregator="fedbuff",
+    cfg = _cfg(
+        "random", rounds=6, mode="async", aggregator="fedbuff",
         aggregator_kwargs={"staleness_mode": "poly", "staleness_exp": 0.7},
         buffer_size=3, max_versions=4, profile=prof,
     )
+    ref_params, ref_sel, ref_losses = _reference_async_run(small_task, cfg)
     res = run_engine(AsyncEngine(small_task, cfg))
     np.testing.assert_array_equal(np.asarray(res.selection), ref_sel)
     np.testing.assert_array_equal(

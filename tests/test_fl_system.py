@@ -8,7 +8,8 @@ import pytest
 from repro.configs.paper_cnn import MNIST_CNN
 from repro.core import load_metric, make_policy
 from repro.data.synthetic import make_image_dataset
-from repro.fl import FLConfig, make_cnn_task, make_lm_task, run_training
+from repro.engine import RunConfig, make_engine, run_engine
+from repro.fl import make_cnn_task, make_lm_task
 from repro.fl.server import broadcast_to_cohort, cohort_indices, fedavg_aggregate
 
 
@@ -28,13 +29,17 @@ def small_task():
     return make_cnn_task(SMALL_CNN, train, test, n_clients=20)
 
 
-def _fl(policy, rounds=8, **kw):
+def _cfg(policy, rounds=8, **kw):
     base = dict(
         n_clients=20, k=4, m=6, policy=policy, rounds=rounds,
         local_epochs=2, batch_size=10, eval_every=rounds,
     )
     base.update(kw)
-    return FLConfig(**base)
+    return RunConfig(**base)
+
+
+def _run(task, cfg):
+    return run_engine(make_engine(task, cfg))
 
 
 def test_cohort_indices_padding():
@@ -71,21 +76,21 @@ def test_fedavg_aggregate_kernel_path_matches():
 
 @pytest.mark.parametrize("policy", ["random", "markov"])
 def test_training_improves_accuracy(small_task, policy):
-    out = run_training(small_task, _fl(policy))
-    accs = out["history"]["accuracy"]
+    out = _run(small_task, _cfg(policy)).history()
+    accs = out["accuracy"]
     assert accs[-1] > 0.2  # 10-class synthetic after 8 rounds
-    assert np.isfinite(out["history"]["train_loss"]).all()
+    assert np.isfinite(out["train_loss"]).all()
 
 
 @pytest.mark.parametrize("policy", ["oldest_age", "round_robin"])
 def test_other_policies_run(small_task, policy):
-    out = run_training(small_task, _fl(policy, rounds=3))
-    assert np.isfinite(out["history"]["train_loss"]).all()
+    out = _run(small_task, _cfg(policy, rounds=3))
+    assert np.isfinite(out.history()["train_loss"]).all()
 
 
 def test_markov_load_stats_in_training(small_task):
-    out = run_training(small_task, _fl("markov", rounds=60, local_epochs=1))
-    stats = out["load_stats"]
+    out = _run(small_task, _cfg("markov", rounds=60, local_epochs=1))
+    stats = out.load_stats
     # n/k = 5 exactly and m=6 >= 5: the optimal policy is deterministic —
     # every client selected every 5th round, Var*[X] = 0 (Theorem 2)
     v_opt = load_metric.optimal_var(20, 4, 6)
@@ -100,10 +105,10 @@ def test_lm_task_federated():
 
     cfg = get_arch("tinyllama-1.1b").reduced()
     task = make_lm_task(cfg, n_clients=8, seq_len=32, docs_per_client=4)
-    fl = FLConfig(n_clients=8, k=2, m=4, policy="markov", rounds=3,
-                  local_epochs=1, batch_size=2, lr0=0.05, eval_every=3)
-    out = run_training(task, fl)
-    assert np.isfinite(out["history"]["eval_loss"]).all()
+    cfg = RunConfig(n_clients=8, k=2, m=4, policy="markov", rounds=3,
+                    local_epochs=1, batch_size=2, lr0=0.05, eval_every=3)
+    out = _run(task, cfg)
+    assert np.isfinite(out.history()["eval_loss"]).all()
 
 
 def test_server_state_checkpoint_roundtrip(tmp_path, small_task):
